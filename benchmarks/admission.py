@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import (
     AdmissionController,
     AdmissionError,
@@ -193,6 +194,7 @@ def run(out_path: str = "BENCH_admission.json", *, n_apps: int = 6,
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_admission.json")
     ap.add_argument("--apps", type=int, default=6)

@@ -26,6 +26,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import (
     APP_NAMES,
     DYNAP_SE,
@@ -166,6 +167,7 @@ def run(out_path: str = "BENCH_binding_opt.json", *, apps=APP_NAMES,
 
 def main() -> None:
     """CLI entry point (see module docstring for usage)."""
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_binding_opt.json")
     ap.add_argument("--quick", action="store_true",

@@ -28,6 +28,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import (
     DYNAP_SE,
     AdmissionController,
@@ -300,6 +301,7 @@ def run(out_path: str = "BENCH_compile.json", *, smoke: bool = False):
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_compile.json")
     ap.add_argument("--smoke", action="store_true",

@@ -30,6 +30,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import (
     APP_NAMES,
     DYNAP_SE,
@@ -264,6 +265,7 @@ def run(out_path: str = "BENCH_energy.json", *, smoke: bool = False,
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_energy.json")
     ap.add_argument("--smoke", action="store_true",

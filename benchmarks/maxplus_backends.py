@@ -43,6 +43,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import maxplus as mp
 from repro.core.engine import pad_stack_to_buckets
 from repro.core.maxplus import EdgeStack, mcr_batch
@@ -267,6 +268,7 @@ def run(out_path: str = "BENCH_maxplus.json", *, smoke: bool = False,
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_maxplus.json")
     ap.add_argument("--smoke", action="store_true",

@@ -21,8 +21,11 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.compile_cache import configure_compile_cache
+
 
 def main() -> None:
+    configure_compile_cache()
     from . import paper_figures, roofline
 
     want = sys.argv[1] if len(sys.argv) > 1 else None

@@ -25,12 +25,14 @@ burst admissions/s beats the stored pre-refactor burst baseline
 (10.716/s on the reference host) with ``never_regressed`` true.
 
 ``--devices N`` adds the device-scaling sweep: for each count ``d`` up
-to ``N`` a SUBPROCESS re-runs the burst mode with
-``XLA_FLAGS=--xla_force_host_platform_device_count=d`` (the flag must
-precede the jax import, hence the subprocess) and a ``host_mesh(d)``
-scoring mesh on the controller, so every rebalance's population scoring
-is sharded d ways.  Per-arm trajectories are bit-identical by the
-``mesh=`` contract — the sweep varies wall-clock only.  A separate
+to ``N`` the burst mode is drained again, in this process, with a
+``host_mesh(d)`` scoring mesh over the first ``d`` visible devices on
+the controller, so every rebalance's population scoring is sharded d
+ways.  ``N`` devices must be visible: the chips of an accelerator host,
+or on a CPU host ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+set for the whole process before it starts.  Per-arm trajectories are
+bit-identical by the ``mesh=`` contract — the sweep varies wall-clock
+only.  A separate
 speculative pre-compilation bench (cold controller, the same churn
 drained in waves through a :class:`~repro.core.serving.PrecompilePool`)
 reports the cache-warm-hit-rate.
@@ -42,13 +44,11 @@ import argparse
 import dataclasses
 import json
 import math
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import (
     DYNAP_SE,
     DYNAP_SE_1024,
@@ -84,7 +84,7 @@ def _never_regressed(events) -> bool:
     return ok
 
 
-def _make_controller(hw, joint_budget, mesh=None):
+def make_controller(hw, joint_budget, mesh=None):
     return AdmissionController(
         hw,
         placement="joint",
@@ -133,7 +133,7 @@ def _run_baseline(ctl, stream, requests):
     }
 
 
-def _run_burst(ctl, stream, requests, *, coalesce_window):
+def run_burst(ctl, stream, requests, *, coalesce_window):
     """Submit everything up front, drain with coalesced rebalances."""
     q = ServingQueue(ctl, coalesce_window=coalesce_window)
     submitted_admits = submitted_evicts = 0
@@ -171,7 +171,7 @@ def _run_burst(ctl, stream, requests, *, coalesce_window):
     }
 
 
-def _build_workload(smoke, n_tenants, n_events, scale, joint_budget, seed):
+def build_workload(smoke, n_tenants, n_events, scale, joint_budget, seed):
     """Shared deterministic setup: hardware, tenants, churn, design cache."""
     if smoke:
         hw = dataclasses.replace(DYNAP_SE, n_tiles=64)
@@ -182,7 +182,7 @@ def _build_workload(smoke, n_tenants, n_events, scale, joint_budget, seed):
     names = [s.name for s in tenants]
     stream = _event_stream(names, n_events, seed)
     requests = {}
-    design_ctl = _make_controller(hw, joint_budget)
+    design_ctl = make_controller(hw, joint_budget)
     for snn in tenants:
         art = design_ctl.register(snn)
         requests[snn.name] = _tiles_request(art.clustered.n_clusters)
@@ -202,7 +202,7 @@ def _precompile_bench(
     hit/miss accounting — ``hit_rate`` is the cache-warm-hit-rate stat
     of the device-scaling section.
     """
-    ctl = _make_controller(hw, joint_budget)
+    ctl = make_controller(hw, joint_budget)
     pool = PrecompilePool(
         ctl, source={s.name: s for s in tenants},
         top_k=max(4, len(tenants) // 8),
@@ -243,19 +243,19 @@ def serving_bench(
     """Run both modes over the same churn; return ``(rows, payload, ok)``."""
     t0 = time.perf_counter()
     hw, tenants, stream, requests, design_ctl, n_tenants, n_events = (
-        _build_workload(smoke, n_tenants, n_events, scale, joint_budget, seed)
+        build_workload(smoke, n_tenants, n_events, scale, joint_budget, seed)
     )
     design_wall_s = time.perf_counter() - t0
 
     # baseline: fresh controller, per-event rebalancing
-    base_ctl = _make_controller(hw, joint_budget)
+    base_ctl = make_controller(hw, joint_budget)
     base_ctl.artifacts = design_ctl.artifacts   # share the design cache
     baseline = _run_baseline(base_ctl, stream, requests)
 
     # burst: fresh controller, coalesced rebalancing
-    burst_ctl = _make_controller(hw, joint_budget)
+    burst_ctl = make_controller(hw, joint_budget)
     burst_ctl.artifacts = design_ctl.artifacts
-    burst = _run_burst(
+    burst = run_burst(
         burst_ctl, stream, requests, coalesce_window=coalesce_window
     )
 
@@ -265,13 +265,12 @@ def serving_bench(
         joint_budget=joint_budget, coalesce_window=coalesce_window,
     )
 
-    # device-scaling sweep: one subprocess per forced host-device count
+    # device-scaling sweep over the visible devices, in this process
     device_scaling = None
     if devices > 0:
         device_scaling = _device_sweep(
-            devices, smoke=smoke, n_tenants=n_tenants, n_events=n_events,
-            scale=scale, joint_budget=joint_budget,
-            coalesce_window=coalesce_window, seed=seed,
+            devices, hw, stream, requests, design_ctl.artifacts,
+            joint_budget=joint_budget, coalesce_window=coalesce_window,
         )
         device_scaling["cache_warm_hit_rate"] = precompile["hit_rate"]
 
@@ -333,84 +332,39 @@ def _device_counts(n: int) -> list[int]:
     return sorted({1} | {d for d in (2, 4, 8, 16) if d <= n} | {int(n)})
 
 
-def _device_arm(
-    d: int, *, smoke, n_tenants, n_events, scale,
-    joint_budget, coalesce_window, seed,
-) -> dict:
-    """One sweep arm — runs INSIDE the forced-device-count subprocess.
-
-    Re-derives the identical workload (same seed), shares the design
-    cache, and drains the burst with a ``host_mesh(d)`` scoring mesh on
-    the controller; ``d == 1`` runs unsharded in the same forced-device
-    environment so every arm pays identical interpreter overheads.
-    """
-    import jax
-
-    from repro.launch.sharding import host_mesh, mesh_devices
-
-    hw, tenants, stream, requests, design_ctl, n_tenants, n_events = (
-        _build_workload(smoke, n_tenants, n_events, scale, joint_budget, seed)
-    )
-    mesh = host_mesh(d) if d > 1 else None
-    ctl = _make_controller(hw, joint_budget, mesh=mesh)
-    ctl.artifacts = design_ctl.artifacts
-    burst = _run_burst(
-        ctl, stream, requests, coalesce_window=coalesce_window
-    )
-    return {
-        "devices_requested": d,
-        "devices_visible": len(jax.devices()),
-        "mesh_devices": len(mesh_devices(mesh)) if mesh is not None else 1,
-        "admissions_per_s": burst["admissions_per_s"],
-        "event_loop_s": burst["event_loop_s"],
-        "admitted": burst["service"]["admitted"],
-        "drained": burst["drained"],
-        "never_regressed": burst["never_regressed"],
-    }
-
-
 def _device_sweep(
-    n_devices: int, *, smoke, n_tenants, n_events, scale,
-    joint_budget, coalesce_window, seed,
+    n_devices: int, hw, stream, requests, artifacts, *,
+    joint_budget, coalesce_window,
 ) -> dict:
-    """Admissions/s vs forced host-device count, one subprocess per arm.
+    """Admissions/s vs scoring-mesh device count, all arms in this process.
 
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=d`` must be set
-    before jax imports, so each arm is a fresh ``benchmarks.serving
-    --arm d`` subprocess printing its result on a ``##ARM`` stdout line.
+    Each arm drains the same burst on a fresh controller that shares the
+    design cache; ``d == 1`` runs unsharded.  ``host_mesh(d)`` raises
+    when fewer than ``d`` devices are visible, so a short host fails the
+    run instead of measuring a smaller mesh.
     """
+    from repro.launch.sharding import host_mesh
+
     counts = _device_counts(n_devices)
     arms = []
     for d in counts:
-        env = os.environ.copy()
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={d}"
-        ).strip()
-        cmd = [
-            sys.executable, "-m", "benchmarks.serving", "--arm", str(d),
-            "--tenants", str(n_tenants), "--events", str(n_events),
-            "--scale", str(scale), "--window", str(coalesce_window),
-            "--seed", str(seed),
-        ]
-        if smoke:
-            cmd.append("--smoke")
-        proc = subprocess.run(cmd, capture_output=True, text=True, env=env)
-        arm = None
-        for line in proc.stdout.splitlines():
-            if line.startswith("##ARM "):
-                arm = json.loads(line[len("##ARM "):])
-        if proc.returncode != 0 or arm is None:
-            arm = {
-                "devices_requested": d,
-                "error": (proc.stderr or "no ##ARM output").strip()[-2000:],
-                "admissions_per_s": 0.0,
-                "drained": False,
-                "never_regressed": False,
-            }
-        arms.append(arm)
-    aps = [float(a.get("admissions_per_s", 0.0)) for a in arms]
-    base = aps[0] if aps and aps[0] > 0 else 0.0
+        ctl = make_controller(
+            hw, joint_budget, mesh=host_mesh(d) if d > 1 else None
+        )
+        ctl.artifacts = artifacts
+        burst = run_burst(
+            ctl, stream, requests, coalesce_window=coalesce_window
+        )
+        arms.append({
+            "devices": d,
+            "admissions_per_s": burst["admissions_per_s"],
+            "event_loop_s": burst["event_loop_s"],
+            "admitted": burst["service"]["admitted"],
+            "drained": burst["drained"],
+            "never_regressed": burst["never_regressed"],
+        })
+    aps = [a["admissions_per_s"] for a in arms]
+    base = aps[0] if aps[0] > 0 else 0.0
     # 5% tolerance absorbs wall-clock noise on shared CI hosts
     monotonic = all(b >= a * 0.95 for a, b in zip(aps, aps[1:]))
     speedup = round(aps[-1] / base, 3) if base else 0.0
@@ -421,9 +375,7 @@ def _device_sweep(
         "speedup_at_max_devices": speedup,
         "target_speedup": 1.5,
         "target_met": bool(base and speedup >= 1.5),
-        "sweep_ok": all(
-            a.get("drained") and a.get("never_regressed") for a in arms
-        ),
+        "sweep_ok": all(a["drained"] and a["never_regressed"] for a in arms),
         "arms": arms,
     }
 
@@ -437,6 +389,7 @@ def run(out_path: str = "BENCH_serving.json", *, smoke: bool = False,
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_serving.json")
     ap.add_argument("--smoke", action="store_true",
@@ -447,17 +400,8 @@ def main() -> None:
     ap.add_argument("--window", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--devices", type=int, default=0,
-                    help="device-scaling sweep up to N forced host devices")
-    ap.add_argument("--arm", type=int, default=0, help=argparse.SUPPRESS)
+                    help="device-scaling sweep up to N visible devices")
     args = ap.parse_args()
-    if args.arm:
-        arm = _device_arm(
-            args.arm, smoke=args.smoke, n_tenants=args.tenants,
-            n_events=args.events, scale=args.scale, joint_budget=(1, 6),
-            coalesce_window=args.window, seed=args.seed,
-        )
-        print("##ARM " + json.dumps(arm))
-        raise SystemExit(0)
     rows, summary, ok = run(
         args.out, smoke=args.smoke, n_tenants=args.tenants,
         n_events=args.events, scale=args.scale,

@@ -37,6 +37,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import (
     DYNAP_SE,
     DYNAP_SE_1024,
@@ -246,6 +247,7 @@ def run(out_path: str = "BENCH_stress.json", *, smoke: bool = False,
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_stress.json")
     ap.add_argument("--smoke", action="store_true",

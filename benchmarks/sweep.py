@@ -23,6 +23,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import (
     DYNAP_SE,
     APP_NAMES,
@@ -133,6 +134,7 @@ def speedup_sweep(app_name: str = "MLP-MNIST", n_candidates: int = 48,
 
 # ======================================================================
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="3 small apps + smaller speedup sweep")

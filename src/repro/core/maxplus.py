@@ -561,9 +561,9 @@ def _pack_csr_chunk(
     stack: EdgeStack, lo0: Optional[np.ndarray]
 ) -> Optional[tuple]:
     """Host-side packing of one (chunk of an) EdgeStack for the device
-    bisection: flat batched CSR -> layout operands + bisection bounds.
+    bisection: flat batched CSR -> ELL operands + bisection bounds.
 
-    Returns ``(operands, layout, lo, hi, has_cycle)`` or ``None`` when the
+    Returns ``(operands, lo, hi, has_cycle)`` or ``None`` when the
     chunk has no finite edge at all (every row is acyclic padding — the
     caller reports those rows as ``-inf`` without a solve).  Packing a
     row subset independently is exact: the ELL width tracks the chunk's
@@ -601,17 +601,10 @@ def _pack_csr_chunk(
     upper = np.clip(max_in.reshape(b, n), 0.0, None).sum(axis=1)
     lo, hi, has_cycle = _bisection_bounds(stack, upper, lo0)
 
-    from repro.kernels.ops import _on_tpu as _kernels_on_tpu
-
-    if _kernels_on_tpu():
-        operands = (src_ord, dst_ord, w_ord, t_ord, row_flat[order])
-        layout = "segment-pallas"
-    else:
-        operands = _ell_pack(
-            src_ord, dst_ord, w_ord, t_ord, b * n, uniq_keys, seg_starts
-        )
-        layout = "ell"
-    return operands, layout, lo, hi, has_cycle
+    operands = _ell_pack(
+        src_ord, dst_ord, w_ord, t_ord, b * n, uniq_keys, seg_starts
+    )
+    return operands, lo, hi, has_cycle
 
 
 def _mcr_batch_csr(
@@ -660,11 +653,11 @@ def _mcr_batch_csr(
         packed = _pack_csr_chunk(stack, lo0)
         if packed is None:
             return np.full(b, NEG_INF)
-        operands, layout, lo, hi, has_cycle = packed
+        operands, lo, hi, has_cycle = packed
         lo, hi, has_cycle, deadlocked = kbell.mcr_bisect_device(
             operands, lo, hi, has_cycle,
             n_actors=n, rel_tol=rel_tol, k_probes=k_probes, max_steps=steps,
-            detect_deadlock=detect_deadlock, layout=layout,
+            detect_deadlock=detect_deadlock,
             device=devices[0] if devices else None,
         )
         res = np.where(has_cycle, 0.5 * (lo + hi), NEG_INF)
@@ -683,7 +676,7 @@ def _mcr_batch_csr(
     dead = np.zeros(b, dtype=bool)
     chunk_slices = row_chunks(b, n_chunks)
     rows_max = max(sl.stop - sl.start for sl in chunk_slices)
-    chunks, slices, devs, layout = [], [], [], None
+    chunks, slices, devs = [], [], []
     for k, sl in enumerate(chunk_slices):
         m = sl.stop - sl.start
         pad = rows_max - m
@@ -701,8 +694,7 @@ def _mcr_batch_csr(
         packed = _pack_csr_chunk(sub, lo0_c)
         if packed is None:
             continue                       # all-padding rows stay -inf
-        operands, layout, lo_c, hi_c, hc_c = packed
-        chunks.append((operands, lo_c, hi_c, hc_c))
+        chunks.append(packed)
         slices.append(sl)
         devs.append(devices[k % len(devices)])
     if not chunks:
@@ -710,7 +702,7 @@ def _mcr_batch_csr(
     lo, hi, has_cycle, deadlocked = kbell.mcr_bisect_device_sharded(
         chunks, devs,
         n_actors=n, rel_tol=rel_tol, k_probes=k_probes, max_steps=steps,
-        detect_deadlock=detect_deadlock, layout=layout,
+        detect_deadlock=detect_deadlock,
     )
     for k, sl in enumerate(slices):
         m = sl.stop - sl.start
@@ -752,24 +744,12 @@ def _ell_pack(
     return ell_src, ell_w, ell_t
 
 
-def _on_tpu() -> bool:
-    # lazy: keep repro.core importable without pulling jax in at load time
-    try:
-        from repro.kernels.ops import _on_tpu as kernels_on_tpu
-
-        return kernels_on_tpu()
-    except Exception:  # pragma: no cover - jax is a hard dep in practice
-        return False
-
-
 def _on_accelerator() -> bool:
-    # lazy for the same reason; any non-CPU jax device (TPU *or* GPU)
-    try:
-        from repro.kernels.ops import _on_accelerator as kernels_on_accel
+    # lazy: keep repro.core importable without pulling jax in at load time;
+    # any non-CPU jax device (TPU *or* GPU)
+    from repro.kernels.ops import _on_accelerator as kernels_on_accel
 
-        return kernels_on_accel()
-    except Exception:  # pragma: no cover - jax is a hard dep in practice
-        return False
+    return kernels_on_accel()
 
 
 #: squaring rounds the last :func:`_mcr_batch_dense` call actually ran,

@@ -1,10 +1,9 @@
 """Pallas TPU kernels for the perf-critical compute layers.
 
   maxplus_matmul  — (max,+) semiring matmul for Max-Plus MCM analysis (VPU)
-  maxplus_bellman — device-resident CSR/segment max-plus Bellman-Ford
-                    lambda-search (the exact "csr-jit" mcr_batch backend:
-                    multi-lambda probing, ELLPACK or segment-Pallas layout,
-                    donated distance buffers)
+  maxplus_bellman — device-resident ELLPACK max-plus Bellman-Ford
+                    lambda-search (the exact "csr-jit" mcr_batch backend,
+                    float64, multi-lambda probing; plain jnp, no Pallas)
   lif_crossbar    — fused crossbar matvec (MXU) + LIF neuron update (VPU)
   flash_attention — block-wise online-softmax attention (MXU+VPU)
   mamba_scan      — chunked selective-state-space scan (VPU)

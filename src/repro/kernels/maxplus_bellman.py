@@ -9,9 +9,9 @@ executes the WHOLE search as one jitted program (the ``"csr-jit"``
 backend):
 
   * the bisection state (lo, hi, has_cycle) and the ``(B*n, K)`` distance
-    buffer live on device across all probe rounds — the scratch buffer is
-    donated, so XLA reuses the allocation in place instead of copying it
-    through every loop step;
+    buffer live on device across all probe rounds — the distances are
+    created inside the program, so nothing but the edge arrays and the
+    interval bounds crosses from the host;
   * every relaxation sweep evaluates ``K`` probe lambdas per row at once
     (a broadcast axis on the edge weights).  The relaxation round count
     per sweep is pinned at the Bellman-Ford bound (~``n+1``) regardless
@@ -22,37 +22,26 @@ backend):
     are masked out of the convergence test, so one slow row never drags
     the batch through extra relaxation rounds.
 
-Two relaxation layouts, selected per backend:
+The relaxation uses an ELLPACK layout on every platform: incoming edges
+of every destination node padded to the max in-degree ``d``, so the
+per-round segment fold becomes a dense ``dist[ell_src] + ww`` gather and
+a ``max`` over the degree axis.  No scatter anywhere; this is what XLA
+vectorizes well on CPU and TPU alike (the scatter-based ``segment_max``
+lowering costs several times a numpy ``reduceat`` per round on CPU).
 
-``"ell"``
-    ELLPACK: incoming edges of every destination node padded to the max
-    in-degree ``d`` — the per-round segment fold becomes a dense
-    ``dist[ell_src] + ww`` gather and a ``max`` over the degree axis.
-    No scatter anywhere; this is what CPU/GPU XLA vectorizes well (the
-    scatter-based ``segment_max`` lowering costs several times a numpy
-    ``reduceat`` per round on CPU).
-
-``"segment"`` / ``"segment-pallas"``
-    Flat dst-sorted CSR folded by :func:`jax.ops.segment_max` (the
-    oracle) or by the Pallas kernel below (TPU: sorted segment ids
-    accumulate through the sequential grid, no padding blow-up when the
-    in-degree distribution is skewed).
-
-Everything here is float64 (``jax.experimental.enable_x64`` scoped to
-these calls): the bisection must resolve 1e-8-class relative tolerances,
-which float32 intervals cannot represent.  Host-side packing (the CSR
-sort, the ELL build, the path bounds) stays in
-:mod:`repro.core.maxplus`; this module is pure array-in/array-out.
+Everything here is float64 (``jax.enable_x64(True)`` scoped to these
+calls; XLA:TPU emulates it with float32 pairs): the bisection must
+resolve 1e-8-class relative tolerances, which float32 intervals cannot
+represent.  Host-side packing (the CSR sort, the ELL build, the path
+bounds) stays in :mod:`repro.core.maxplus`; this module is pure
+array-in/array-out.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
 
 NEG_INF = float("-inf")
 
@@ -65,73 +54,22 @@ NEG_INF = float("-inf")
 #: wide vector units amortize larger K.
 DEFAULT_K_PROBES = 3
 
-_LAYOUTS = ("ell", "segment", "segment-pallas")
-
-
-# ======================================================================
-# Pallas segment-max: sorted segment ids, sequential-grid accumulation
-# ======================================================================
-def _segment_max_kernel(cand_ref, seg_ref, out_ref):
-    """Fold edge candidates into their destination segments (max).
-
-    The grid walks edge blocks sequentially (TPU grid order), the output
-    block is the WHOLE (n_segments, K) accumulator (constant index map),
-    so read-modify-write per edge is race-free; block 0 initializes the
-    accumulator to -inf, the (max,+) neutral element.  Padded edge rows
-    carry -inf candidates and segment 0 — they never change a maximum.
-    """
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[...] = jnp.full_like(out_ref, NEG_INF)
-
-    def body(i, carry):
-        sid = seg_ref[i]
-        out_ref[sid, :] = jnp.maximum(out_ref[sid, :], cand_ref[i, :])
-        return carry
-
-    jax.lax.fori_loop(0, cand_ref.shape[0], body, 0)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("n_segments", "block_e", "interpret")
-)
-def segment_max_pallas(
-    cand, seg_ids, *, n_segments: int, block_e: int = 512,
-    interpret: bool = True,
-):
-    """(E, K) candidates + sorted (E,) segment ids -> (n_segments, K) maxima.
-
-    Segments the edges never touch stay at -inf (exactly like
-    ``jax.ops.segment_max``).  ``interpret=True`` runs the kernel body in
-    Python — the CPU validation mode; on TPU pass ``interpret=False``.
-    The whole accumulator must fit one VMEM block, so this kernel is for
-    stacks up to ~10^5 destination keys; the jnp oracle has no such cap.
-    """
-    e, k = cand.shape
-    ep = -(-e // block_e) * block_e
-    if ep != e:
-        cand = jnp.pad(cand, ((0, ep - e), (0, 0)), constant_values=NEG_INF)
-        seg_ids = jnp.pad(seg_ids, (0, ep - e))
-    seg_ids = seg_ids.astype(jnp.int32)
-    return pl.pallas_call(
-        _segment_max_kernel,
-        grid=(ep // block_e,),
-        in_specs=[
-            pl.BlockSpec((block_e, k), lambda b: (b, 0)),
-            pl.BlockSpec((block_e,), lambda b: (b,)),
-        ],
-        out_specs=pl.BlockSpec((n_segments, k), lambda b: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_segments, k), cand.dtype),
-        interpret=interpret,
-    )(cand, seg_ids)
-
-
 # ======================================================================
 # the jitted device-resident bisection
 # ======================================================================
+def _follow(ptr, idx):
+    """``ptr[idx[i, j], j]`` for every (i, j): one pointer hop per probe.
+
+    A 1-D gather per probe column.  The equivalent
+    ``take_along_axis(ptr, idx, axis=0)`` gathers row by row, and its TPU
+    code generation grows with the row count: 34 s to compile at 98,304
+    rows against 0.4 s for this form (v5e, ahead of time).
+    """
+    return jax.vmap(lambda p, i: p[i], in_axes=1, out_axes=1)(ptr, idx)
+
+
 def csr_bisect(
-    dist0,          # (B*n, K) float64 scratch, donated (contents ignored)
-    operands,       # layout-specific edge arrays, see mcr_bisect_device
+    operands,       # (ell_src, ell_w, ell_t), each (B*n, d)
     lo,             # (B,) float64 sound lower bounds
     hi,             # (B,) float64 interval tops (> any finite cycle ratio)
     has_cycle,      # (B,) bool rows already known cyclic
@@ -142,7 +80,6 @@ def csr_bisect(
     max_steps: int = 40,
     max_rounds: int = 0,       # relaxation rounds per probe; 0 -> n+1
     detect_deadlock: bool = False,
-    layout: str = "ell",
 ):
     """Whole-stack lambda bisection, resident on the default device.
 
@@ -165,70 +102,38 @@ def csr_bisect(
     key_row = jnp.arange(nk, dtype=jnp.int32) // n_actors
     ids = jnp.arange(nk, dtype=jnp.int32)
 
-    if layout == "ell":
-        ell_src, ell_w, ell_t = operands
+    ell_src, ell_w, ell_t = operands
+    slot = jnp.arange(ell_src.shape[1], dtype=jnp.int32)
 
-        def make_round(lams):
-            # (B*n, 1, K) probe weights fold into the gathered candidates;
-            # XLA fuses the subtraction into the degree-axis reduction, so
-            # nothing (B*n, d, K)-sized is ever materialized
-            lam_key = lams[key_row][:, None, :]
+    def make_round(lams):
+        # (B*n, 1, K) probe weights fold into the gathered candidates;
+        # XLA fuses the subtraction into the degree-axis reduction, so
+        # nothing (B*n, d, K)-sized is ever materialized
+        lam_key = lams[key_row][:, None, :]
 
-            def best_of(dist):
-                cand = (
-                    dist[ell_src]
-                    + (ell_w[:, :, None] - lam_key * ell_t[:, :, None])
-                )
-                return cand.max(axis=1)
+        def best_of(dist):
+            cand = (
+                dist[ell_src]
+                + (ell_w[:, :, None] - lam_key * ell_t[:, :, None])
+            )
+            return cand.max(axis=1)
 
-            def witness(dist):
-                cand = (
-                    dist[ell_src]
-                    + (ell_w[:, :, None] - lam_key * ell_t[:, :, None])
-                )
-                amax = cand.argmax(axis=1)                      # (B*n, K)
-                best = jnp.take_along_axis(
-                    cand, amax[:, None, :], axis=1
-                )[:, 0, :]
-                return best, jnp.take_along_axis(ell_src, amax, axis=1)
+        def witness(dist):
+            cand = (
+                dist[ell_src]
+                + (ell_w[:, :, None] - lam_key * ell_t[:, :, None])
+            )
+            amax = cand.argmax(axis=1)                      # (B*n, K)
+            # one-hot select of the argmax source: the same values as
+            # take_along_axis, whose per-row gather takes the TPU
+            # compiler ~35 s at 1e5 rows (this form: ~1 s)
+            hit = slot[None, :, None] == amax[:, None, :]
+            psrc = jnp.where(hit, ell_src[:, :, None], 0).sum(axis=1)
+            return cand.max(axis=1), psrc
 
-            return best_of, witness
-    else:
-        src_sorted, dst_sorted, w_sorted, t_sorted, row_sorted = operands
-        src_f = src_sorted.astype(jnp.float64)
+        return best_of, witness
 
-        if layout == "segment-pallas":
-            def _segmax(cand):
-                return segment_max_pallas(
-                    cand, dst_sorted, n_segments=nk, interpret=False
-                )
-        else:
-            def _segmax(cand):
-                return jax.ops.segment_max(
-                    cand, dst_sorted, num_segments=nk,
-                    indices_are_sorted=True,
-                )
-
-        def make_round(lams):
-            lam_e = lams[row_sorted]                        # (E_tot, K)
-            ww = w_sorted[:, None] - lam_e * t_sorted[:, None]
-
-            def best_of(dist):
-                return _segmax(dist[src_sorted] + ww)
-
-            def witness(dist):
-                cand = dist[src_sorted] + ww
-                best = _segmax(cand)
-                # second fold recovers a predecessor achieving each max
-                at_max = cand >= best[dst_sorted]
-                psrc = _segmax(
-                    jnp.where(at_max, src_f[:, None], NEG_INF)
-                ).astype(jnp.int32)
-                return best, psrc
-
-            return best_of, witness
-
-    def probe(dist, lams, active):
+    def probe(lams, active):
         """(B, k) positive-cycle verdicts at per-row probe lambdas.
 
         Longest-path Bellman-Ford with three resolution rules, applied
@@ -254,8 +159,7 @@ def csr_bisect(
         best_of, witness = make_round(lams)
         resolved0 = jnp.broadcast_to(~active[:, None], (b, k))
         positive0 = jnp.zeros((b, k), dtype=bool)
-        dist = jnp.zeros((nk, k), dtype=dist.dtype) if k != dist.shape[1] \
-            else dist * 0.0
+        dist = jnp.zeros((nk, k), dtype=lo.dtype)
 
         def cond(carry):
             _, resolved, _, blk = carry
@@ -283,40 +187,40 @@ def csr_bisect(
             over = (dist > over_node).reshape(b, n_actors, k).any(axis=1)
             anc = par
             for _ in range(n_doublings):
-                anc = jnp.take_along_axis(anc, anc, axis=0)
-            on_cycle = jnp.take_along_axis(par, anc, axis=0) != anc
+                anc = _follow(anc, anc)
+            on_cycle = _follow(par, anc) != anc
             cyc = on_cycle.reshape(b, n_actors, k).any(axis=1)
             positive = positive | ((over | cyc) & ~resolved)
             resolved = resolved | over | cyc | ~improving
             return dist, resolved, positive, blk + 1
 
-        dist, resolved, positive, _ = jax.lax.while_loop(
+        _, resolved, positive, _ = jax.lax.while_loop(
             cond, body, (dist, resolved0, positive0, 0)
         )
         # probes still improving after n+1 rounds contain a positive cycle
-        return positive | ~resolved, dist
+        return positive | ~resolved
 
     deadlocked = jnp.zeros(b, dtype=bool)
     if detect_deadlock:
         # any cycle with >= 1 token has ratio <= upper < hi, so a positive
         # cycle AT lam = hi can only be a zero-token (deadlock) cycle with
         # positive weight sum — always the case for tau > 0 graphs
-        pos, _ = probe(dist0, hi[:, None], jnp.ones(b, dtype=bool))
+        pos = probe(hi[:, None], jnp.ones(b, dtype=bool))
         deadlocked = pos[:, 0]
 
     frac = jnp.arange(1, k_probes + 1, dtype=lo.dtype) / (k_probes + 1)
 
     def outer_cond(carry):
-        lo, hi, _, _, step = carry
+        lo, hi, _, step = carry
         tol = rel_tol * jnp.maximum(1.0, jnp.abs(hi))
         return (step < max_steps) & ((hi - lo) > tol).any()
 
     def outer_body(carry):
-        lo, hi, has_cycle, dist, step = carry
+        lo, hi, has_cycle, step = carry
         tol = rel_tol * jnp.maximum(1.0, jnp.abs(hi))
         active = ((hi - lo) > tol) & ~deadlocked
         lams = lo[:, None] + (hi - lo)[:, None] * frac[None, :]  # ascending
-        positive, dist = probe(dist, lams, active)
+        positive = probe(lams, active)
         # positives form a prefix of the ascending probes (positive iff
         # lam < rho); the count locates rho in (lams[c-1], lams[c]]
         c = jnp.sum(positive & active[:, None], axis=1)
@@ -326,34 +230,21 @@ def csr_bisect(
         lo = jnp.where(active & (c > 0), pick(c - 1), lo)
         hi = jnp.where(active & (c < k_probes), pick(c), hi)
         has_cycle = has_cycle | (active & (c > 0))
-        return lo, hi, has_cycle, dist, step + 1
+        return lo, hi, has_cycle, step + 1
 
-    lo, hi, has_cycle, _, _ = jax.lax.while_loop(
-        outer_cond, outer_body, (lo, hi, has_cycle, dist0, 0)
+    lo, hi, has_cycle, _ = jax.lax.while_loop(
+        outer_cond, outer_body, (lo, hi, has_cycle, 0)
     )
     return lo, hi, has_cycle, deadlocked
 
 
-_CSR_STATIC = (
-    "n_actors", "k_probes", "max_steps", "max_rounds",
-    "detect_deadlock", "layout",
+_csr_bisect = jax.jit(
+    csr_bisect,
+    static_argnames=(
+        "n_actors", "k_probes", "max_steps", "max_rounds",
+        "detect_deadlock",
+    ),
 )
-#: Donating the distance scratch lets XLA alias it in place through the
-#: bisection loop on accelerators; CPU buffers are never donatable, so a
-#: separate non-donating entry avoids a warning per call there.
-_csr_bisect_donating = jax.jit(
-    csr_bisect, static_argnames=_CSR_STATIC, donate_argnums=(0,)
-)
-_csr_bisect_plain = jax.jit(csr_bisect, static_argnames=_CSR_STATIC)
-
-
-def _default_layout(layout: str | None) -> str:
-    from .ops import _on_tpu
-
-    if layout is None:
-        layout = "segment-pallas" if _on_tpu() else "ell"
-    assert layout in _LAYOUTS, layout
-    return layout
 
 
 def _dispatch_bisect(
@@ -365,44 +256,24 @@ def _dispatch_bisect(
     max_steps: int,
     max_rounds: int,
     detect_deadlock: bool,
-    layout: str,
     device=None,
 ):
-    """Enqueue one chunk's bisection (inside an ``enable_x64`` scope).
+    """Enqueue one chunk's bisection (inside a ``jax.enable_x64`` scope).
 
     Returns the four result arrays WITHOUT forcing them to host: jax
     dispatch is async, so a caller placing successive chunks on different
     devices overlaps their execution and synchronizes only at the final
     ``np.asarray`` gather.  ``device=None`` keeps the default placement.
     """
-    from .ops import _on_accelerator
-
-    fn = _csr_bisect_donating if _on_accelerator() else _csr_bisect_plain
-    b = int(np.asarray(lo).shape[0])
-
     def put(x, dtype):
         arr = np.asarray(x, dtype=dtype)
         return jax.device_put(arr, device) if device is not None \
             else jnp.asarray(arr)
 
-    if layout == "ell":
-        ell_src, ell_w, ell_t = operands
-        ops_dev = (
-            put(ell_src, np.int32),
-            put(ell_w, np.float64),
-            put(ell_t, np.float64),
-        )
-    else:
-        src, dst, w, tok, row = operands
-        ops_dev = (
-            put(src, np.int32),
-            put(dst, np.int32),
-            put(w, np.float64),
-            put(tok, np.float64),
-            put(row, np.int32),
-        )
-    return fn(
-        put(np.zeros((b * n_actors, k_probes)), np.float64),
+    ell_src, ell_w, ell_t = operands
+    ops_dev = (put(ell_src, np.int32), put(ell_w, np.float64),
+               put(ell_t, np.float64))
+    return _csr_bisect(
         ops_dev,
         put(lo, np.float64),
         put(hi, np.float64),
@@ -413,7 +284,6 @@ def _dispatch_bisect(
         max_steps=max_steps,
         max_rounds=max_rounds,
         detect_deadlock=detect_deadlock,
-        layout=layout,
     )
 
 
@@ -426,28 +296,24 @@ def mcr_bisect_device(
     max_steps: int = 40,
     max_rounds: int = 0,
     detect_deadlock: bool = False,
-    layout: str | None = None,
     device=None,
 ):
     """Host-facing entry: numpy CSR/ELL arrays in, numpy results out.
 
-    ``operands`` is ``(ell_src, ell_w, ell_t)`` for the ``"ell"`` layout
-    (each ``(B*n, d)``) or ``(src, dst, w, tok, row)`` dst-sorted flat
-    arrays for the segment layouts.  Scopes ``enable_x64`` around
-    conversion, tracing and execution so the bisection runs in float64
-    without flipping the process-global jax precision (the Pallas
-    semiring kernels stay float32).  ``layout`` defaults to the Pallas
-    segment kernel on TPU and ELL everywhere else.  ``device`` pins the
-    whole solve to one specific jax device (the sharded path's per-chunk
-    placement); ``None`` keeps the default device.
+    ``operands`` is ``(ell_src, ell_w, ell_t)``, each ``(B*n, d)``.
+    Scopes ``jax.enable_x64(True)`` around conversion, tracing and
+    execution so the bisection runs in float64 without flipping the
+    process-global jax precision (the Pallas semiring kernels stay
+    float32).  ``device`` pins the whole solve to one specific jax device
+    (the sharded path's per-chunk placement); ``None`` keeps the default
+    device.
     """
-    layout = _default_layout(layout)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = _dispatch_bisect(
             operands, lo, hi, has_cycle,
             n_actors=n_actors, rel_tol=rel_tol, k_probes=k_probes,
             max_steps=max_steps, max_rounds=max_rounds,
-            detect_deadlock=detect_deadlock, layout=layout, device=device,
+            detect_deadlock=detect_deadlock, device=device,
         )
         lo, hi, has_cycle, deadlocked = (np.asarray(x) for x in out)
     return lo, hi, has_cycle, deadlocked
@@ -463,7 +329,6 @@ def mcr_bisect_device_sharded(
     max_steps: int = 40,
     max_rounds: int = 0,
     detect_deadlock: bool = False,
-    layout: str | None = None,
 ):
     """Shard-friendly solve entry: one bisection chunk per mesh device.
 
@@ -488,15 +353,14 @@ def mcr_bisect_device_sharded(
     chunk order.
     """
     assert chunks, "need at least one chunk"
-    layout = _default_layout(layout)
     devices = list(devices) or [None]
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         futs = [
             _dispatch_bisect(
                 operands, lo, hi, has_cycle,
                 n_actors=n_actors, rel_tol=rel_tol, k_probes=k_probes,
                 max_steps=max_steps, max_rounds=max_rounds,
-                detect_deadlock=detect_deadlock, layout=layout,
+                detect_deadlock=detect_deadlock,
                 device=devices[k % len(devices)],
             )
             for k, (operands, lo, hi, has_cycle) in enumerate(chunks)

@@ -9,7 +9,9 @@ so this kernel targets the VPU — blocks of A and B are staged in VMEM and
 the reduction is an 8x128-vreg ``max`` over broadcast sums.  Block shapes
 are multiples of (8, 128) so loads/stores stay register-aligned; K is the
 minor grid dimension with a VMEM accumulator initialized to -inf and flushed
-on the last K step.
+on the last K step.  Inside a block the K reduction walks ``unroll_k``-wide
+chunks at static offsets (a Python loop, not ``fori_loop``): Mosaic has no
+lowering for a ``dynamic_slice`` of a loaded block.
 
 Neutral element is -inf: padding rows/cols with -inf keeps results exact for
 non-multiple shapes (handled in ops.py).
@@ -27,6 +29,15 @@ from jax.experimental.pallas import tpu as pltpu
 NEG = float("-inf")
 
 
+def _reduce_chunks(a_ref, b_ref, acc, unroll_k: int):
+    """``max(acc, A (x) B)`` for one (bm, bk) x (bk, bn) block pair."""
+    for c in range(a_ref.shape[1] // unroll_k):
+        ks = slice(c * unroll_k, (c + 1) * unroll_k)
+        part = jnp.max(a_ref[:, ks][:, :, None] + b_ref[ks, :][None], axis=1)
+        acc = jnp.maximum(acc, part)
+    return acc
+
+
 def _maxplus_kernel(a_ref, b_ref, out_ref, acc_ref, *, n_k: int, unroll_k: int):
     """One (bm, bn) output block; K iterated via grid dim 2."""
     k = pl.program_id(2)
@@ -35,19 +46,11 @@ def _maxplus_kernel(a_ref, b_ref, out_ref, acc_ref, *, n_k: int, unroll_k: int):
     def _init():
         acc_ref[...] = jnp.full_like(acc_ref[...], NEG)
 
-    a = a_ref[...]  # (bm, bk)
-    b = b_ref[...]  # (bk, bn)
-    bk = a.shape[1]
-
-    # Reduce over k in sub-chunks to bound the (bm, chunk, bn) VREG footprint.
-    def body(c, acc):
-        a_c = jax.lax.dynamic_slice_in_dim(a, c * unroll_k, unroll_k, axis=1)
-        b_c = jax.lax.dynamic_slice_in_dim(b, c * unroll_k, unroll_k, axis=0)
-        part = jnp.max(a_c[:, :, None] + b_c[None, :, :], axis=1)
-        return jnp.maximum(acc, part)
-
-    acc = jax.lax.fori_loop(0, bk // unroll_k, body, acc_ref[...])
-    acc_ref[...] = acc
+    # (bm, bk) x (bk, bn): reduce over k in sub-chunks to bound the
+    # (bm, chunk, bn) VREG footprint
+    acc_ref[...] = _reduce_chunks(
+        a_ref.at[...], b_ref.at[...], acc_ref[...], unroll_k
+    )
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -105,18 +108,9 @@ def _maxplus_bmm_kernel(a_ref, b_ref, out_ref, acc_ref, *, n_k: int, unroll_k: i
     def _init():
         acc_ref[...] = jnp.full_like(acc_ref[...], NEG)
 
-    a = a_ref[0]  # (bm, bk)
-    b = b_ref[0]  # (bk, bn)
-    bk = a.shape[1]
-
-    def body(c, acc):
-        a_c = jax.lax.dynamic_slice_in_dim(a, c * unroll_k, unroll_k, axis=1)
-        b_c = jax.lax.dynamic_slice_in_dim(b, c * unroll_k, unroll_k, axis=0)
-        part = jnp.max(a_c[:, :, None] + b_c[None, :, :], axis=1)
-        return jnp.maximum(acc, part)
-
-    acc = jax.lax.fori_loop(0, bk // unroll_k, body, acc_ref[...])
-    acc_ref[...] = acc
+    acc_ref[...] = _reduce_chunks(
+        a_ref.at[0], b_ref.at[0], acc_ref[...], unroll_k
+    )
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -135,13 +129,13 @@ def _maxplus_bmv_kernel(a_ref, x_ref, out_ref, acc_ref, *, n_k: int):
         acc_ref[...] = jnp.full_like(acc_ref[...], NEG)
 
     a = a_ref[0]          # (bm, bk)
-    x = x_ref[...]        # (1, bk)
+    x = x_ref[0]          # (1, bk)
     part = jnp.max(a + x, axis=1)[None, :]          # (1, bm)
     acc_ref[...] = jnp.maximum(acc_ref[...], part)
 
     @pl.when(k == n_k - 1)
     def _flush():
-        out_ref[...] = acc_ref[...]
+        out_ref[0] = acc_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
@@ -159,7 +153,11 @@ def maxplus_bmv(
     whole candidate batch is one launch.  The reduction runs as a VPU max
     over the broadcast (bm, bk) sum — a vector has no MXU path anyway, and
     batching amortizes the launch.  Shapes must be block multiples; use
-    :func:`repro.kernels.ops.maxplus_bmv` for arbitrary shapes.
+    :func:`repro.kernels.ops.maxplus_bmv` for arbitrary shapes.  The
+    vectors ride as ``(g, 1, k)`` / ``(g, 1, m)`` so every block's last
+    two dims are ``(1, 128)``-multiples of the full ``(1, k)`` trailing
+    shape — a ``(1, bk)`` block of a ``(g, k)`` array breaks the TPU
+    (8, 128) block rule.
     """
     g, m, k = a.shape
     g2, k2 = x.shape
@@ -175,13 +173,13 @@ def maxplus_bmv(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bm, bk), lambda gg, i, kk: (gg, i, kk)),
-            pl.BlockSpec((1, bk), lambda gg, i, kk: (gg, kk)),
+            pl.BlockSpec((1, 1, bk), lambda gg, i, kk: (gg, 0, kk)),
         ],
-        out_specs=pl.BlockSpec((1, bm), lambda gg, i, kk: (gg, i)),
-        out_shape=jax.ShapeDtypeStruct((g, m), a.dtype),
+        out_specs=pl.BlockSpec((1, 1, bm), lambda gg, i, kk: (gg, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((g, 1, m), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, bm), a.dtype)],
         interpret=interpret,
-    )(a, x)
+    )(a, x[:, None, :])[:, 0, :]
 
 
 @functools.partial(

@@ -33,12 +33,10 @@ def _on_accelerator() -> bool:
 
     Backend auto-selection must not key on ``default_backend() == "tpu"``
     alone: on a CUDA host that test is false and the exact analysis would
-    silently fall back to host numpy.
+    silently fall back to host numpy.  A backend that fails to initialise
+    raises here rather than reading as "no accelerator".
     """
-    try:
-        return any(d.platform != "cpu" for d in jax.devices())
-    except Exception:  # pragma: no cover - no backend initialised at all
-        return False
+    return any(d.platform != "cpu" for d in jax.devices())
 
 
 def _pad_to(x: jax.Array, mults: tuple[int, ...], fill: float) -> jax.Array:

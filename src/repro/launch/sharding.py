@@ -32,30 +32,13 @@ def current_mesh() -> Optional[Mesh]:
     return getattr(_STATE, "mesh", None)
 
 
-def _mesh_context(mesh: Mesh):
-    """Version-tolerant global-mesh context.
-
-    ``jax.set_mesh`` (newer jax) and ``jax.sharding.use_mesh`` (a brief
-    intermediate spelling) both set the mesh that resolves bare
-    ``PartitionSpec`` axis names; on jax versions with neither (e.g.
-    0.4.x), ``Mesh`` itself is the context manager with that meaning.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use = getattr(jax.sharding, "use_mesh", None)
-    if use is not None:
-        return use(mesh)
-    return mesh
-
-
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh):
     """Make sharding constraints active (dry-run / real runs enter this)."""
     prev = getattr(_STATE, "mesh", None)
     _STATE.mesh = mesh
     try:
-        with _mesh_context(mesh):
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         _STATE.mesh = prev
@@ -102,14 +85,21 @@ def host_mesh(n_devices: Optional[int] = None, *, axis: str = "data") -> Mesh:
 
     The serving benchmarks force ``N`` host devices with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` and build the
-    scoring mesh here; on a real accelerator pod the same call meshes the
-    accelerators.  ``n_devices`` clamps to what is actually visible.
+    scoring mesh here; on a real accelerator host the same call meshes the
+    accelerators.  Asking for more devices than are visible raises: a
+    mesh silently smaller than requested would mislabel every result
+    measured on it.
     """
     devs = jax.devices()
     if n_devices is not None:
         if n_devices < 1:
             raise ValueError(f"n_devices must be >= 1, got {n_devices}")
-        devs = devs[: min(int(n_devices), len(devs))]
+        if n_devices > len(devs):
+            raise ValueError(
+                f"host_mesh({n_devices}) needs {n_devices} devices, but "
+                f"only {len(devs)} are visible: {devs}"
+            )
+        devs = devs[: int(n_devices)]
     return Mesh(np.asarray(devs), (axis,))
 
 

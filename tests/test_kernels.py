@@ -59,6 +59,18 @@ def test_maxplus_bmm_kernel_interpret_matches_ref():
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-5)
 
 
+def test_maxplus_bmv_kernel_interpret_matches_ref():
+    """The matvec kernel itself (interpret mode): vectors ride as
+    (g, 1, k) blocks, the layout the TPU block rule forces."""
+    from repro.kernels.maxplus_matmul import maxplus_bmv as kern
+
+    a = RNG.normal(size=(3, 256, 384)).astype(np.float32)
+    x = RNG.normal(size=(3, 384)).astype(np.float32)
+    out = kern(jnp.asarray(a), jnp.asarray(x), interpret=True)
+    exp = ref.maxplus_bmv_ref(jnp.asarray(a), jnp.asarray(x))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-6)
+
+
 def test_maxplus_bmm_neginf_padding_rows():
     """-inf rows/cols (the EdgeStack padding convention) stay neutral."""
     g, n = 2, 64

@@ -279,6 +279,19 @@ def test_mcr_batch_devices_requires_csr_jit():
         mcr_batch(stack, backend="edges", devices=jax.devices() * 2)
 
 
+def test_host_mesh_raises_when_too_few_devices_visible():
+    """A mesh silently smaller than requested would mislabel every
+    measurement taken on it: asking past the visible devices raises."""
+    import jax
+
+    from repro.launch.sharding import host_mesh
+
+    n = len(jax.devices())
+    assert host_mesh(n).devices.size == n
+    with pytest.raises(ValueError, match="visible"):
+        host_mesh(n + 1)
+
+
 def test_batch_execute_mesh_matches_unsharded():
     import jax
     from jax.sharding import Mesh
