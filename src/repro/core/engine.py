@@ -36,11 +36,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 from typing import Optional, Sequence, Union
 
 import numpy as np
 
+from .. import obs
 from .hardware import ChipState, HardwareConfig
 from .maxplus import (
     NEG_INF,
@@ -120,6 +120,7 @@ class OrderBatch:
 OrdersLike = Union[Sequence[Optional[Sequence[Sequence[int]]]], OrderBatch]
 
 
+@obs.span("project")
 def project_order_batch(single_order: Sequence[int], bindings) -> OrderBatch:
     """Lemma-1 projection of ONE total order onto B bindings, batched.
 
@@ -346,6 +347,7 @@ class ChipMetrics:
     read_charge: float        # row-length-weighted crossbar reads (all rows)
 
 
+@obs.span("stack_build")
 def stack_hardware_aware(
     app: SDFG,
     bindings,
@@ -707,14 +709,10 @@ class EngineReport:
     per-candidate chip energy (pJ per iteration,
     :meth:`~repro.core.hardware.HardwareConfig.chip_energy`; ``inf`` for
     dead rows) and the raw :class:`ChipMetrics` accumulators.
-    ``build_time_s`` / ``analysis_time_s`` are wall-clock seconds of the
-    EdgeStack build and the batched analysis.
     """
 
     periods: np.ndarray                 # (B,) microseconds of model time
     starts: Optional[np.ndarray]        # (B, n_actors) microseconds, or None
-    build_time_s: float
-    analysis_time_s: float
     energies: Optional[np.ndarray] = None   # (B,) pJ per iteration, or None
     metrics: Optional[ChipMetrics] = None
 
@@ -759,7 +757,6 @@ class PreparedExec:
     rel_tol: float
     with_energy: bool
     chip_state: Optional[ChipState]
-    build_time_s: float
 
 
 def prepare_execution(
@@ -781,7 +778,6 @@ def prepare_execution(
     into a single analysis call (:func:`batch_execute_fused`).
     """
     bindings = _as_binding_matrix(bindings, app.n_actors)
-    t0 = time.perf_counter()
     built = stack_hardware_aware(
         app, bindings, hw, orders_list, relax_shortcuts=relax_shortcuts,
         with_metrics=with_energy, chip_state=chip_state,
@@ -801,7 +797,6 @@ def prepare_execution(
         rel_tol=rel_tol,
         with_energy=with_energy,
         chip_state=chip_state,
-        build_time_s=time.perf_counter() - t0,
     )
 
 
@@ -809,7 +804,6 @@ def finish_execution(
     prep: PreparedExec,
     periods: np.ndarray,
     *,
-    analysis_time_s: float,
     starts: Optional[np.ndarray] = None,
 ) -> EngineReport:
     """Turn one prepared batch's solved periods into an :class:`EngineReport`.
@@ -837,8 +831,6 @@ def finish_execution(
     return EngineReport(
         periods=periods,
         starts=starts,
-        build_time_s=prep.build_time_s,
-        analysis_time_s=analysis_time_s,
         energies=energies,
         metrics=prep.metrics,
     )
@@ -941,7 +933,6 @@ def batch_execute_fused(
     which candidate wins.
     """
     assert preps, "need at least one prepared execution to fuse"
-    t1 = time.perf_counter()
     devices = _solve_devices(mesh)
     backend = _resolve_backend(backend, len(devices))
     if backend != "csr-jit":
@@ -968,11 +959,7 @@ def batch_execute_fused(
         fused, backend=backend, rel_tol=rel_tol, lo0=lo0,
         devices=devices or None,
     )
-    analysis_s = (time.perf_counter() - t1) / len(preps)
-    return [
-        finish_execution(p, periods[s], analysis_time_s=analysis_s)
-        for p, s in zip(preps, slices)
-    ]
+    return [finish_execution(p, periods[s]) for p, s in zip(preps, slices)]
 
 
 def batch_execute(
@@ -1041,7 +1028,6 @@ def batch_execute(
         rate_scale=rate_scale, relax_shortcuts=not with_starts,
     )
 
-    t1 = time.perf_counter()
     devices = _solve_devices(mesh)
     backend = _resolve_backend(backend, len(devices))
     if backend != "csr-jit":
@@ -1066,11 +1052,7 @@ def batch_execute(
         finite = np.isfinite(x)
         lo = np.where(finite, x, np.inf).min(axis=1, keepdims=True)
         starts = np.where(finite, x - lo, np.inf)[:prep.n_rows, :prep.n_act]
-    return finish_execution(
-        prep, periods,
-        analysis_time_s=time.perf_counter() - t1,
-        starts=starts,
-    )
+    return finish_execution(prep, periods, starts=starts)
 
 
 def batch_throughputs(

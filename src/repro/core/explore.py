@@ -28,6 +28,7 @@ from typing import Callable, Optional, Sequence, Union
 
 import numpy as np
 
+from .. import obs
 from .binding import bind_ours, bind_pycarl, bind_spinemap, cut_spikes_batch
 from .engine import batch_execute, project_order_batch
 from .hardware import DYNAP_SE, CrossbarConfig, HardwareConfig, TileConfig
@@ -405,6 +406,7 @@ class SubsetScores:
         return self.subsets[int(np.argmin(self.energies))]
 
 
+@obs.span("subset_scoring")
 def score_free_tile_subsets(
     clustered: ClusteredSNN,
     hw: HardwareConfig,
@@ -434,10 +436,11 @@ def score_free_tile_subsets(
     subsets = candidate_subsets(free, k, max_candidates=max_candidates)
     sub_hw = dataclasses.replace(hw, n_tiles=k)
     kwargs = binder_kwargs or {}
-    try:
-        bres = binder(clustered, sub_hw, **kwargs)
-    except TypeError:  # binders without the kwargs (spinemap)
-        bres = binder(clustered, sub_hw)
+    with obs.span("bind"):
+        try:
+            bres = binder(clustered, sub_hw, **kwargs)
+        except TypeError:  # binders without the kwargs (spinemap)
+            bres = binder(clustered, sub_hw)
     virt_orders = project_order(list(single_order), bres.binding, k)
 
     # one (B, n_clusters) binding matrix + ONE vectorized Lemma-1

@@ -44,6 +44,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from .. import obs
 from .sdfg import SDFG
 
 NEG_INF = -math.inf
@@ -557,6 +558,7 @@ def mcr_batch(
     return np.where(deadlocked, np.inf, res) if detect_deadlock else res
 
 
+@obs.span("pack")
 def _pack_csr_chunk(
     stack: EdgeStack, lo0: Optional[np.ndarray]
 ) -> Optional[tuple]:
@@ -607,6 +609,7 @@ def _pack_csr_chunk(
     return operands, lo, hi, has_cycle
 
 
+@obs.span("solve")
 def _mcr_batch_csr(
     stack: EdgeStack,
     *,

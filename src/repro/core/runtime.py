@@ -66,6 +66,7 @@ from typing import Optional, Union
 
 import numpy as np
 
+from .. import obs
 from .binding import BindingResult, LoadWeights, bind_ours
 from .engine import (
     CompileCacheStats,
@@ -196,6 +197,7 @@ def single_tile_order(
     return orders[0], time.perf_counter() - t0
 
 
+@obs.span("project")
 def project_order(
     order: list[int], binding: np.ndarray, n_tiles: int
 ) -> list[list[int]]:
@@ -335,7 +337,9 @@ def runtime_admit(
         virt_binding = scores.binding
     else:
         sub_hw = dataclasses.replace(state.hw, n_tiles=len(free))
-        virt_binding = bind_ours(clustered, sub_hw, weights=weights).binding
+        with obs.span("bind"):
+            virt_binding = bind_ours(clustered, sub_hw,
+                                     weights=weights).binding
     refined = False
     if optimize_budget is not None:
         from .optimize import optimize_binding
@@ -374,17 +378,19 @@ def runtime_admit(
         phys_orders[phys] = sub_orders[virt]
     t_sched = time.perf_counter() - t1
 
-    app = sdfg_from_clusters(clustered, hw=state.hw)
-    if chip_state is not None and (not chip_state.pristine or rate_scale != 1.0):
-        # degraded chip: the howard-solver path is chip-state-unaware, so
-        # score the admitted configuration through the batched engine
-        rep = batch_execute(
-            app, phys_binding, state.hw, [phys_orders],
-            chip_state=chip_state, rate_scale=rate_scale,
-        )
-        thr = float(rep.throughputs[0])
-    else:
-        thr = analyze_throughput(app, phys_binding, state.hw, phys_orders)
+    with obs.span("report_score"):
+        app = sdfg_from_clusters(clustered, hw=state.hw)
+        if chip_state is not None and (
+                not chip_state.pristine or rate_scale != 1.0):
+            # degraded chip: the howard-solver path is chip-state-unaware,
+            # so score the admitted configuration through the batched engine
+            rep = batch_execute(
+                app, phys_binding, state.hw, [phys_orders],
+                chip_state=chip_state, rate_scale=rate_scale,
+            )
+            thr = float(rep.throughputs[0])
+        else:
+            thr = analyze_throughput(app, phys_binding, state.hw, phys_orders)
     state.allocated[clustered.snn.name] = list(free)
     return CompileReport(
         app=clustered.snn.name,
@@ -668,6 +674,7 @@ class AdmissionController:
         return self.register(app), cached
 
     # -- run time -------------------------------------------------------
+    @obs.span("admit")
     def admit(
         self,
         app: Union[str, SNN, ClusteredSNN],
@@ -760,6 +767,7 @@ class AdmissionController:
         """App completed normally: free its tiles."""
         return self._release(app, "finish")
 
+    @obs.span("evict")
     def evict(self, app: str) -> list[int]:
         """Forcibly preempt a running app (the Fig.-11 displacement case).
 

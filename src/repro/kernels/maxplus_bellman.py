@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 NEG_INF = float("-inf")
 
 #: Probe lambdas evaluated per relaxation sweep (the broadcast axis K).
@@ -53,6 +55,10 @@ NEG_INF = float("-inf")
 #: dispatch).  K=3 is the measured sweet spot on CPU; accelerators with
 #: wide vector units amortize larger K.
 DEFAULT_K_PROBES = 3
+
+#: Relaxation rounds per block of the probe loop: each block ends in one
+#: convergence and cycle verdict.
+CHECK_EVERY = 4
 
 # ======================================================================
 # the jitted device-resident bisection
@@ -83,19 +89,25 @@ def csr_bisect(
 ):
     """Whole-stack lambda bisection, resident on the default device.
 
-    Returns ``(lo, hi, has_cycle, deadlocked)``; the caller's result is
-    ``0.5 * (lo + hi)`` where ``has_cycle`` (and ``inf``/``-inf``
+    Returns ``(lo, hi, has_cycle, deadlocked, counts)``; the caller's
+    result is ``0.5 * (lo + hi)`` where ``has_cycle`` (and ``inf``/``-inf``
     elsewhere).  ``upper`` — the per-row simple-path weight bound whose
     breach flags a pumping positive cycle — is recovered from ``hi``
     (the host passes ``hi = max(upper, lo) + 1``).  Mirrors
     :func:`repro.core.maxplus._positive_cycle_masks` exactly, with the
     K-probe broadcast axis and converged-row masking on top.
+
+    ``counts`` is an int32 ``(3,)`` array that the loops carry beside the
+    search and that feeds nothing back into it: the outer bisection steps;
+    the probe loop's blocks of ``CHECK_EVERY`` relaxation rounds, summed
+    over every step and the deadlock probe; and, summed over the same
+    blocks, the (row, probe) pairs not yet resolved when the block began,
+    counting rows with a finite edge only (all--inf pad rows left out).
     """
     b = lo.shape[0]
     nk = b * n_actors
     rounds = max_rounds if max_rounds else n_actors + 1
-    check_every = 4                        # relaxation rounds per verdict
-    n_blocks = -(-rounds // check_every)
+    n_blocks = -(-rounds // CHECK_EVERY)
     n_doublings = max(1, (n_actors + 1).bit_length())
     upper = hi - 1.0                       # host invariant: hi = upper' + 1
     over_node = jnp.repeat(upper, n_actors)[:, None] + 1.0   # (B*n, 1)
@@ -104,6 +116,7 @@ def csr_bisect(
 
     ell_src, ell_w, ell_t = operands
     slot = jnp.arange(ell_src.shape[1], dtype=jnp.int32)
+    real = jnp.isfinite(ell_w).reshape(b, -1).any(axis=1)[:, None]  # (B, 1)
 
     def make_round(lams):
         # (B*n, 1, K) probe weights fold into the gathered candidates;
@@ -137,7 +150,7 @@ def csr_bisect(
         """(B, k) positive-cycle verdicts at per-row probe lambdas.
 
         Longest-path Bellman-Ford with three resolution rules, applied
-        every ``check_every`` rounds: a probe with no improving node has
+        every ``CHECK_EVERY`` rounds: a probe with no improving node has
         settled (no positive cycle — the fixpoint is monotone); a node
         past the simple-path bound can only have been pumped by a
         positive cycle; and — the rule the numpy backend cannot afford —
@@ -162,13 +175,14 @@ def csr_bisect(
         dist = jnp.zeros((nk, k), dtype=lo.dtype)
 
         def cond(carry):
-            _, resolved, _, blk = carry
+            _, resolved, _, blk, _ = carry
             return (blk < n_blocks) & ~resolved.all()
 
         def body(carry):
-            dist, resolved, positive, blk = carry
+            dist, resolved, positive, blk, live = carry
+            live = live + jnp.sum(~resolved & real, dtype=jnp.int32)
             dist = jax.lax.fori_loop(
-                0, check_every - 1,
+                0, CHECK_EVERY - 1,
                 lambda _, d: jnp.maximum(d, best_of(d)), dist,
             )
             # the block's last round doubles as the verdict pass: its
@@ -192,35 +206,36 @@ def csr_bisect(
             cyc = on_cycle.reshape(b, n_actors, k).any(axis=1)
             positive = positive | ((over | cyc) & ~resolved)
             resolved = resolved | over | cyc | ~improving
-            return dist, resolved, positive, blk + 1
+            return dist, resolved, positive, blk + 1, live
 
-        _, resolved, positive, _ = jax.lax.while_loop(
-            cond, body, (dist, resolved0, positive0, 0)
+        _, resolved, positive, blk, live = jax.lax.while_loop(
+            cond, body, (dist, resolved0, positive0, 0, jnp.int32(0))
         )
         # probes still improving after n+1 rounds contain a positive cycle
-        return positive | ~resolved
+        return positive | ~resolved, blk.astype(jnp.int32), live
 
     deadlocked = jnp.zeros(b, dtype=bool)
+    blocks = live = jnp.int32(0)
     if detect_deadlock:
         # any cycle with >= 1 token has ratio <= upper < hi, so a positive
         # cycle AT lam = hi can only be a zero-token (deadlock) cycle with
         # positive weight sum — always the case for tau > 0 graphs
-        pos = probe(hi[:, None], jnp.ones(b, dtype=bool))
+        pos, blocks, live = probe(hi[:, None], jnp.ones(b, dtype=bool))
         deadlocked = pos[:, 0]
 
     frac = jnp.arange(1, k_probes + 1, dtype=lo.dtype) / (k_probes + 1)
 
     def outer_cond(carry):
-        lo, hi, _, step = carry
+        lo, hi, _, step, _, _ = carry
         tol = rel_tol * jnp.maximum(1.0, jnp.abs(hi))
         return (step < max_steps) & ((hi - lo) > tol).any()
 
     def outer_body(carry):
-        lo, hi, has_cycle, step = carry
+        lo, hi, has_cycle, step, blocks, live = carry
         tol = rel_tol * jnp.maximum(1.0, jnp.abs(hi))
         active = ((hi - lo) > tol) & ~deadlocked
         lams = lo[:, None] + (hi - lo)[:, None] * frac[None, :]  # ascending
-        positive = probe(lams, active)
+        positive, blk, blk_live = probe(lams, active)
         # positives form a prefix of the ascending probes (positive iff
         # lam < rho); the count locates rho in (lams[c-1], lams[c]]
         c = jnp.sum(positive & active[:, None], axis=1)
@@ -230,12 +245,13 @@ def csr_bisect(
         lo = jnp.where(active & (c > 0), pick(c - 1), lo)
         hi = jnp.where(active & (c < k_probes), pick(c), hi)
         has_cycle = has_cycle | (active & (c > 0))
-        return lo, hi, has_cycle, step + 1
+        return lo, hi, has_cycle, step + 1, blocks + blk, live + blk_live
 
-    lo, hi, has_cycle, _ = jax.lax.while_loop(
-        outer_cond, outer_body, (lo, hi, has_cycle, 0)
+    lo, hi, has_cycle, step, blocks, live = jax.lax.while_loop(
+        outer_cond, outer_body, (lo, hi, has_cycle, 0, blocks, live)
     )
-    return lo, hi, has_cycle, deadlocked
+    counts = jnp.stack([step.astype(jnp.int32), blocks, live])
+    return lo, hi, has_cycle, deadlocked, counts
 
 
 _csr_bisect = jax.jit(
@@ -260,7 +276,7 @@ def _dispatch_bisect(
 ):
     """Enqueue one chunk's bisection (inside a ``jax.enable_x64`` scope).
 
-    Returns the four result arrays WITHOUT forcing them to host: jax
+    Returns the five result arrays WITHOUT forcing them to host: jax
     dispatch is async, so a caller placing successive chunks on different
     devices overlaps their execution and synchronizes only at the final
     ``np.asarray`` gather.  ``device=None`` keeps the default placement.
@@ -287,6 +303,43 @@ def _dispatch_bisect(
     )
 
 
+def _tally(counts, operands, b: int, k_probes: int) -> dict:
+    """One chunk's loop counts, pulled to the host, as solve counters.
+
+    ``steps``: bisection steps; ``rounds``: relaxation rounds, one probe
+    loop after another; ``probe_rounds``: (row, probe) pairs those rounds
+    relaxed, over the rows with a finite edge; ``live_probe_rounds``: the
+    ones among them not yet resolved; ``relaxations``: single edge
+    relaxations, ``(B*n) * d * K`` a round, pad rows and slots included.
+    """
+    steps, blocks, live = (int(x) for x in np.asarray(counts))
+    _, ell_w, _ = operands
+    keys, d = np.shape(ell_w)
+    rows = int(np.isfinite(ell_w).reshape(b, -1).any(axis=1).sum())
+    rounds = CHECK_EVERY * blocks
+    return {
+        "steps": steps,
+        "rounds": rounds,
+        "probe_rounds": rounds * rows * k_probes,
+        "live_probe_rounds": CHECK_EVERY * live,
+        "relaxations": rounds * keys * d * k_probes,
+    }
+
+
+def _record(span, tallies: list) -> None:
+    """Adds one solve's counters (``solve.calls`` and ``solve.<key>`` of
+    :func:`_tally`) to the attached recorder and puts them on the solve's
+    ``device_solve`` span.  The chunks of a sharded solve run side by side
+    on their devices, so its steps and rounds are those of its longest
+    chunk; its pairs and relaxations add up over the chunks."""
+    total = {key: (max if key in ("steps", "rounds") else sum)(
+        t[key] for t in tallies) for key in tallies[0]}
+    span.attrs.update(total)
+    obs.count("solve.calls")
+    for key, n in total.items():
+        obs.count(f"solve.{key}", n)
+
+
 def mcr_bisect_device(
     operands, lo, hi, has_cycle,
     *,
@@ -307,15 +360,22 @@ def mcr_bisect_device(
     float32).  ``device`` pins the whole solve to one specific jax device
     (the sharded path's per-chunk placement); ``None`` keeps the default
     device.
+
+    The call is the program's ``device_solve`` span.  While a recorder is
+    attached (:func:`repro.obs.recording`) the solve's loop counts come
+    back from the device and are recorded (:func:`_record`); else they
+    never leave it.
     """
-    with jax.enable_x64(True):
+    with obs.span("device_solve") as sp, jax.enable_x64(True):
         out = _dispatch_bisect(
             operands, lo, hi, has_cycle,
             n_actors=n_actors, rel_tol=rel_tol, k_probes=k_probes,
             max_steps=max_steps, max_rounds=max_rounds,
             detect_deadlock=detect_deadlock, device=device,
         )
-        lo, hi, has_cycle, deadlocked = (np.asarray(x) for x in out)
+        lo, hi, has_cycle, deadlocked = (np.asarray(x) for x in out[:4])
+        if obs.recorder() is not None:
+            _record(sp, [_tally(out[4], operands, len(lo), k_probes)])
     return lo, hi, has_cycle, deadlocked
 
 
@@ -350,11 +410,12 @@ def mcr_bisect_device_sharded(
     through extra relaxation sweeps.
 
     Returns concatenated ``(lo, hi, has_cycle, deadlocked)`` rows in
-    chunk order.
+    chunk order.  The call is one ``device_solve`` span and records one
+    solve, as :func:`mcr_bisect_device` does.
     """
     assert chunks, "need at least one chunk"
     devices = list(devices) or [None]
-    with jax.enable_x64(True):
+    with obs.span("device_solve") as sp, jax.enable_x64(True):
         futs = [
             _dispatch_bisect(
                 operands, lo, hi, has_cycle,
@@ -365,7 +426,10 @@ def mcr_bisect_device_sharded(
             )
             for k, (operands, lo, hi, has_cycle) in enumerate(chunks)
         ]
-        parts = [tuple(np.asarray(x) for x in out) for out in futs]
+        parts = [tuple(np.asarray(x) for x in out[:4]) for out in futs]
+        if obs.recorder() is not None:
+            _record(sp, [_tally(out[4], operands, len(lo), k_probes)
+                         for out, (operands, lo, _, _) in zip(futs, chunks)])
     return tuple(
         np.concatenate([p[i] for p in parts]) for i in range(4)
     )
