@@ -571,9 +571,63 @@ def _pack_csr_chunk(
     row subset independently is exact: the ELL width tracks the chunk's
     own in-degree profile and pad slots carry the ``-inf`` neutral
     element, so per-row results never depend on which rows share the
-    pack.
+    pack.  Two or more rows whose finite edges share one topology (the
+    candidates of one app) pack as replicas of one ELL
+    (:func:`_pack_shared`); any other stack packs row by row
+    (:func:`_pack_per_row`).  Both give the same slots in the same order,
+    so the solve's results and counts are the same in either layout.
     """
-    b, n, e = stack.n_graphs, stack.n_actors, stack.n_edges
+    ref = _shared_topology(stack)
+    if ref is None:
+        return _pack_per_row(stack, lo0)
+    return _pack_shared(stack, lo0, ref)
+
+
+def _shared_topology(stack: EdgeStack) -> Optional[int]:
+    """Index of the first row with a finite edge, when the stack has two
+    or more rows and every row with a finite edge has the same finite
+    mask, ``src``, ``dst`` and ``tokens`` on it; else ``None``.  Rows with
+    no finite edge (all--inf padding) do not count."""
+    fin = np.isfinite(stack.weights)
+    real = fin.any(axis=1)
+    if stack.n_graphs < 2 or not real.any():
+        return None
+    ref = int(np.argmax(real))
+    same = fin[real] == fin[ref]
+    for a in (stack.src, stack.dst, stack.tokens):
+        same &= (a[real] == a[ref]) | ~fin[ref]
+    return ref if same.all() else None
+
+
+def _pack_shared(
+    stack: EdgeStack, lo0: Optional[np.ndarray], ref: int
+) -> tuple:
+    """:func:`_pack_csr_chunk` for rows that share row ``ref``'s topology:
+    one ELL over ``n`` nodes with local ids, ``ell_w`` ``(n, d, B)``."""
+    b, n = stack.n_graphs, stack.n_actors
+    keep = np.isfinite(stack.weights[ref])
+    dst = stack.dst[ref, keep]
+    order = np.argsort(dst, kind="stable")
+    uniq_keys, seg_starts = np.unique(dst[order], return_index=True)
+    w_ord = stack.weights[:, keep][:, order]          # (B, E'); pads -inf
+    max_in = np.full((b, n), NEG_INF)
+    max_in[:, uniq_keys] = np.maximum.reduceat(w_ord, seg_starts, axis=1)
+    upper = np.clip(max_in, 0.0, None).sum(axis=1)
+    lo, hi, has_cycle = _bisection_bounds(stack, upper, lo0)
+    operands = _ell_pack(
+        stack.src[ref, keep][order], dst[order], w_ord.T,
+        stack.tokens[ref, keep][order].astype(np.float64), n,
+        uniq_keys, seg_starts,
+    )
+    return operands, lo, hi, has_cycle
+
+
+def _pack_per_row(
+    stack: EdgeStack, lo0: Optional[np.ndarray]
+) -> Optional[tuple]:
+    """:func:`_pack_csr_chunk` row by row: one ELL over ``B*n`` nodes,
+    ``ell_w`` ``(B*n, d, 1)``."""
+    b, n = stack.n_graphs, stack.n_actors
     rows = np.arange(b, dtype=np.int64)[:, None]
     flat_src = (rows * n + stack.src).ravel()
     flat_dst = (rows * n + stack.dst).ravel()
@@ -586,7 +640,6 @@ def _pack_csr_chunk(
     flat_dst = flat_dst[keep]
     w_flat = stack.weights.ravel()[keep]
     t_flat = stack.tokens.ravel()[keep].astype(np.float64)
-    row_flat = np.repeat(np.arange(b, dtype=np.int64), keep.reshape(b, e).sum(axis=1))
     if flat_dst.size == 0:
         return None
     order = np.argsort(flat_dst, kind="stable")
@@ -604,7 +657,7 @@ def _pack_csr_chunk(
     lo, hi, has_cycle = _bisection_bounds(stack, upper, lo0)
 
     operands = _ell_pack(
-        src_ord, dst_ord, w_ord, t_ord, b * n, uniq_keys, seg_starts
+        src_ord, dst_ord, w_ord[:, None], t_ord, b * n, uniq_keys, seg_starts
     )
     return operands, lo, hi, has_cycle
 
@@ -726,7 +779,9 @@ def _ell_pack(
     uniq_keys: np.ndarray,
     seg_starts: np.ndarray,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """dst-sorted flat edges -> ELLPACK ``(B*n, d_max)`` incoming-edge rows.
+    """dst-sorted flat edges -> ELLPACK ``(n_keys, d_max)`` incoming-edge
+    rows; ``w_ord`` is ``(E, R)``, one weight per replica, and ``ell_w``
+    ``(n_keys, d_max, R)``.
 
     Pad slots point at node 0 with -inf weight (the (max,+) neutral), so
     the degree-axis max ignores them.  ``d_max`` is rounded up to the next
@@ -739,7 +794,7 @@ def _ell_pack(
     pos = np.arange(src_ord.size) - np.repeat(seg_starts, counts)
     row_idx = dst_ord
     ell_src = np.zeros((n_keys, d_max), dtype=np.int32)
-    ell_w = np.full((n_keys, d_max), NEG_INF)
+    ell_w = np.full((n_keys, d_max, w_ord.shape[1]), NEG_INF)
     ell_t = np.zeros((n_keys, d_max))
     ell_src[row_idx, pos] = src_ord
     ell_w[row_idx, pos] = w_ord

@@ -8,7 +8,7 @@ that host-side, one python-level relaxation round at a time; this module
 executes the WHOLE search as one jitted program (the ``"csr-jit"``
 backend):
 
-  * the bisection state (lo, hi, has_cycle) and the ``(B*n, K)`` distance
+  * the bisection state (lo, hi, has_cycle) and the node-major distance
     buffer live on device across all probe rounds — the distances are
     created inside the program, so nothing but the edge arrays and the
     interval bounds crosses from the host;
@@ -28,6 +28,11 @@ per-round segment fold becomes a dense ``dist[ell_src] + ww`` gather and
 a ``max`` over the degree axis.  No scatter anywhere; this is what XLA
 vectorizes well on CPU and TPU alike (the scatter-based ``segment_max``
 lowering costs several times a numpy ``reduceat`` per round on CPU).
+When the stack's rows share one topology and differ only in weights (the
+candidate bindings of one app), the ELL is built once over that topology
+and each node carries every row as a replica: the gather then fetches one
+lane-dense row of ``B*K`` distances per edge slot, where a per-row pack
+fetches ``B*n*d`` rows of ``K``, each padded to a full vector tile.
 
 Everything here is float64 (``jax.enable_x64(True)`` scoped to these
 calls; XLA:TPU emulates it with float32 pairs): the bisection must
@@ -75,7 +80,7 @@ def _follow(ptr, idx):
 
 
 def csr_bisect(
-    operands,       # (ell_src, ell_w, ell_t), each (B*n, d)
+    operands,       # (ell_src (N, d), ell_w (N, d, R), ell_t (N, d))
     lo,             # (B,) float64 sound lower bounds
     hi,             # (B,) float64 interval tops (> any finite cycle ratio)
     has_cycle,      # (B,) bool rows already known cyclic
@@ -97,6 +102,14 @@ def csr_bisect(
     :func:`repro.core.maxplus._positive_cycle_masks` exactly, with the
     K-probe broadcast axis and converged-row masking on top.
 
+    The ELL rows are ``N`` nodes, each carrying ``R`` replicas that share
+    its incoming edges and tokens but not their weights: row ``b`` of the
+    stack is replica ``b % R`` of nodes ``(b // R) * n`` onwards.  A
+    per-row pack is ``R = 1, N = B*n``; a stack whose rows share one
+    topology packs as ``R = B, N = n``.  Distances are node-major,
+    ``(N, R*k)`` with column ``r*k + j`` for replica ``r``'s probe ``j``,
+    so a relaxation gathers one row of ``R*k`` distances per edge slot.
+
     ``counts`` is an int32 ``(3,)`` array that the loops carry beside the
     search and that feeds nothing back into it: the outer bisection steps;
     the probe loop's blocks of ``CHECK_EVERY`` relaxation rounds, summed
@@ -104,45 +117,46 @@ def csr_bisect(
     blocks, the (row, probe) pairs not yet resolved when the block began,
     counting rows with a finite edge only (all--inf pad rows left out).
     """
+    ell_src, ell_w, ell_t = operands
     b = lo.shape[0]
-    nk = b * n_actors
+    n_nodes, _, reps = ell_w.shape
+    groups = b // reps                     # = n_nodes // n_actors
     rounds = max_rounds if max_rounds else n_actors + 1
     n_blocks = -(-rounds // CHECK_EVERY)
     n_doublings = max(1, (n_actors + 1).bit_length())
     upper = hi - 1.0                       # host invariant: hi = upper' + 1
-    over_node = jnp.repeat(upper, n_actors)[:, None] + 1.0   # (B*n, 1)
-    key_row = jnp.arange(nk, dtype=jnp.int32) // n_actors
-    ids = jnp.arange(nk, dtype=jnp.int32)
-
-    ell_src, ell_w, ell_t = operands
+    ids = jnp.arange(n_nodes, dtype=jnp.int32)[:, None]
     slot = jnp.arange(ell_src.shape[1], dtype=jnp.int32)
-    real = jnp.isfinite(ell_w).reshape(b, -1).any(axis=1)[:, None]  # (B, 1)
+    real = jnp.isfinite(ell_w).reshape(groups, -1, reps).any(axis=1)
+    real = real.reshape(b, 1)
+
+    def per_row(x, k):
+        """(N, R*k) node flags -> (B, k): any over each row's nodes."""
+        return x.reshape(groups, n_actors, -1).any(axis=1).reshape(b, k)
 
     def make_round(lams):
-        # (B*n, 1, K) probe weights fold into the gathered candidates;
-        # XLA fuses the subtraction into the degree-axis reduction, so
-        # nothing (B*n, d, K)-sized is ever materialized
-        lam_key = lams[key_row][:, None, :]
+        # (N, 1, R*k) probe weights fold into the gathered candidates;
+        # column r*k + j takes replica r's weights, hence the repeats
+        k = lams.shape[1]
+        lam_col = jnp.repeat(lams.reshape(groups, reps * k), n_actors,
+                             axis=0)[:, None, :]
+        w_col = jnp.repeat(ell_w, k, axis=2)
+
+        def cand(dist):
+            return dist[ell_src] + (w_col - lam_col * ell_t[:, :, None])
 
         def best_of(dist):
-            cand = (
-                dist[ell_src]
-                + (ell_w[:, :, None] - lam_key * ell_t[:, :, None])
-            )
-            return cand.max(axis=1)
+            return cand(dist).max(axis=1)
 
         def witness(dist):
-            cand = (
-                dist[ell_src]
-                + (ell_w[:, :, None] - lam_key * ell_t[:, :, None])
-            )
-            amax = cand.argmax(axis=1)                      # (B*n, K)
+            c = cand(dist)
+            amax = c.argmax(axis=1)                         # (N, R*k)
             # one-hot select of the argmax source: the same values as
             # take_along_axis, whose per-row gather takes the TPU
             # compiler ~35 s at 1e5 rows (this form: ~1 s)
             hit = slot[None, :, None] == amax[:, None, :]
             psrc = jnp.where(hit, ell_src[:, :, None], 0).sum(axis=1)
-            return cand.max(axis=1), psrc
+            return c.max(axis=1), psrc
 
         return best_of, witness
 
@@ -170,9 +184,11 @@ def csr_bisect(
         """
         k = lams.shape[1]
         best_of, witness = make_round(lams)
+        over_col = jnp.repeat(upper.reshape(groups, reps), k, axis=1)
+        over_col = over_col[:, None, :] + 1.0                # (G, 1, R*k)
         resolved0 = jnp.broadcast_to(~active[:, None], (b, k))
         positive0 = jnp.zeros((b, k), dtype=bool)
-        dist = jnp.zeros((nk, k), dtype=lo.dtype)
+        dist = jnp.zeros((n_nodes, reps * k), dtype=lo.dtype)
 
         def cond(carry):
             _, resolved, _, blk, _ = carry
@@ -191,19 +207,17 @@ def csr_bisect(
             # the relaxation the round does anyway
             best, psrc = witness(dist)
             # once a round improves nothing, no later round can
-            improving = (
-                (best > dist + 1e-12).reshape(b, n_actors, k).any(axis=1)
-            )
+            improving = per_row(best > dist + 1e-12, k)
             # tight-edge parents: only nodes that can still match or beat
             # their pre-round distance join the cycle-candidate graph
-            par = jnp.where(best >= dist, psrc, ids[:, None])
+            par = jnp.where(best >= dist, psrc, ids)
             dist = jnp.maximum(dist, best)
-            over = (dist > over_node).reshape(b, n_actors, k).any(axis=1)
+            over = (dist.reshape(groups, n_actors, -1) > over_col).any(axis=1)
+            over = over.reshape(b, k)
             anc = par
             for _ in range(n_doublings):
                 anc = _follow(anc, anc)
-            on_cycle = _follow(par, anc) != anc
-            cyc = on_cycle.reshape(b, n_actors, k).any(axis=1)
+            cyc = per_row(_follow(par, anc) != anc, k)
             positive = positive | ((over | cyc) & ~resolved)
             resolved = resolved | over | cyc | ~improving
             return dist, resolved, positive, blk + 1, live
@@ -310,32 +324,43 @@ def _tally(counts, operands, b: int, k_probes: int) -> dict:
     loop after another; ``probe_rounds``: (row, probe) pairs those rounds
     relaxed, over the rows with a finite edge; ``live_probe_rounds``: the
     ones among them not yet resolved; ``relaxations``: single edge
-    relaxations, ``(B*n) * d * K`` a round, pad rows and slots included.
+    relaxations, ``(B*n) * d * K`` a round, pad rows and slots included,
+    in either layout.
     """
     steps, blocks, live = (int(x) for x in np.asarray(counts))
     _, ell_w, _ = operands
-    keys, d = np.shape(ell_w)
-    rows = int(np.isfinite(ell_w).reshape(b, -1).any(axis=1).sum())
+    nodes, d, reps = np.shape(ell_w)
+    rows = int(np.isfinite(ell_w).reshape(b // reps, -1, reps)
+               .any(axis=1).sum())
     rounds = CHECK_EVERY * blocks
     return {
         "steps": steps,
         "rounds": rounds,
         "probe_rounds": rounds * rows * k_probes,
         "live_probe_rounds": CHECK_EVERY * live,
-        "relaxations": rounds * keys * d * k_probes,
+        "relaxations": rounds * nodes * reps * d * k_probes,
     }
 
 
+def _layout(operand_sets) -> str:
+    """``"shared"`` when every chunk's rows share one topology (``R > 1``
+    replicas per ELL node), else ``"per_row"``."""
+    shared = all(np.shape(ops[1])[2] > 1 for ops in operand_sets)
+    return "shared" if shared else "per_row"
+
+
 def _record(span, tallies: list) -> None:
-    """Adds one solve's counters (``solve.calls`` and ``solve.<key>`` of
-    :func:`_tally`) to the attached recorder and puts them on the solve's
-    ``device_solve`` span.  The chunks of a sharded solve run side by side
-    on their devices, so its steps and rounds are those of its longest
-    chunk; its pairs and relaxations add up over the chunks."""
+    """Adds one solve's counters (``solve.calls``, ``solve.shared_calls``
+    and ``solve.<key>`` of :func:`_tally`) to the attached recorder and
+    puts them on the solve's ``device_solve`` span, beside its ``layout``.
+    The chunks of a sharded solve run side by side on their devices, so
+    its steps and rounds are those of its longest chunk; its pairs and
+    relaxations add up over the chunks."""
     total = {key: (max if key in ("steps", "rounds") else sum)(
         t[key] for t in tallies) for key in tallies[0]}
     span.attrs.update(total)
     obs.count("solve.calls")
+    obs.count("solve.shared_calls", int(span.attrs["layout"] == "shared"))
     for key, n in total.items():
         obs.count(f"solve.{key}", n)
 
@@ -353,7 +378,8 @@ def mcr_bisect_device(
 ):
     """Host-facing entry: numpy CSR/ELL arrays in, numpy results out.
 
-    ``operands`` is ``(ell_src, ell_w, ell_t)``, each ``(B*n, d)``.
+    ``operands`` is ``(ell_src, ell_w, ell_t)``, shaped ``(N, d)``,
+    ``(N, d, R)`` and ``(N, d)`` (:func:`csr_bisect`).
     Scopes ``jax.enable_x64(True)`` around conversion, tracing and
     execution so the bisection runs in float64 without flipping the
     process-global jax precision (the Pallas semiring kernels stay
@@ -361,12 +387,14 @@ def mcr_bisect_device(
     (the sharded path's per-chunk placement); ``None`` keeps the default
     device.
 
-    The call is the program's ``device_solve`` span.  While a recorder is
+    The call is the program's ``device_solve`` span; its ``layout`` attr
+    says whether the rows shared one topology.  While a recorder is
     attached (:func:`repro.obs.recording`) the solve's loop counts come
     back from the device and are recorded (:func:`_record`); else they
     never leave it.
     """
-    with obs.span("device_solve") as sp, jax.enable_x64(True):
+    with obs.span("device_solve", layout=_layout([operands])) as sp, \
+            jax.enable_x64(True):
         out = _dispatch_bisect(
             operands, lo, hi, has_cycle,
             n_actors=n_actors, rel_tol=rel_tol, k_probes=k_probes,
@@ -415,7 +443,8 @@ def mcr_bisect_device_sharded(
     """
     assert chunks, "need at least one chunk"
     devices = list(devices) or [None]
-    with obs.span("device_solve") as sp, jax.enable_x64(True):
+    layout = _layout([ops for ops, _, _, _ in chunks])
+    with obs.span("device_solve", layout=layout) as sp, jax.enable_x64(True):
         futs = [
             _dispatch_bisect(
                 operands, lo, hi, has_cycle,

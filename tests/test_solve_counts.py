@@ -86,18 +86,24 @@ def test_recorded_counters_follow_the_loop_counts():
     steps_cap = max(4, int(math.ceil(80 / math.log2(K + 1))) + 1)
     steps, blocks, live = counts(stack, max_steps=steps_cap)
     rounds = kbell.CHECK_EVERY * blocks
+    # the ring's rows share one topology: the relaxations are still
+    # counted over every row's nodes, (B*n) * d * K a round
     assert c == {
         "solve.calls": 1,
+        "solve.shared_calls": 1,
         "solve.steps": steps,
         "solve.rounds": rounds,
         "solve.probe_rounds": rounds * len(lo) * K,
         "solve.live_probe_rounds": kbell.CHECK_EVERY * live,
-        "solve.relaxations": rounds * ell_src.shape[0] * ell_src.shape[1] * K,
+        "solve.relaxations": (rounds * len(lo) * stack.n_actors
+                              * ell_src.shape[1] * K),
     }
     assert [s.name for s in rec.spans] == ["pack", "device_solve", "solve"]
-    # the call's own tally rides on its device_solve span
-    assert rec.spans[1].attrs == {k[len("solve."):]: v for k, v in c.items()
-                                  if k != "solve.calls"}
+    # the call's own tally and layout ride on its device_solve span
+    assert rec.spans[1].attrs == {
+        "layout": "shared",
+        **{k[len("solve."):]: v for k, v in c.items()
+           if k not in ("solve.calls", "solve.shared_calls")}}
 
 
 def _pad(stack, rows):

@@ -11,6 +11,8 @@ The topology is described inside the module fixture, never at import:
 only one process may load the TPU library at a time.
 """
 
+import re
+
 import pytest
 
 import jax
@@ -25,6 +27,10 @@ from repro.kernels.maxplus_matmul import maxplus_bmm, maxplus_bmv, maxplus_matmu
 #: dispatched (csr-jit forced): phase (c), the 224-tenant burst on the
 #: 32x32 chip, and phase (b), the eight full-size Table-1 apps
 ELL_PACKS = {"burst": (64, 192, 64), "table1": (96, 1024, 64)}
+
+#: (B, n, d) of the shared-topology packs of the Table-1 admissions: the
+#: 64 candidate bindings of HeartClass and of LeNet-CIFAR
+SHARED_PACKS = {"heartclass": (64, 1024, 32), "lenet_cifar": (64, 768, 64)}
 
 
 @pytest.fixture(scope="module")
@@ -57,30 +63,53 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("pack", sorted(ELL_PACKS))
-def test_csr_bisect_ell_compiles(one_chip, pack):
-    """The exact float64 solve as ``mcr_bisect_device`` dispatches it
-    (``jax.enable_x64`` scope) at the smoke run's packs."""
-    b, n, d = ELL_PACKS[pack]
+def _compile_bisect(sharding, nodes, d, reps, b, n):
+    """``_csr_bisect`` as ``mcr_bisect_device`` dispatches it (inside a
+    ``jax.enable_x64`` scope), for ELL operands of ``nodes`` nodes with
+    ``reps`` replicas each."""
     with jax.enable_x64(True):
         args = (
             (
-                _spec(one_chip, (b * n, d), jnp.int32),
-                _spec(one_chip, (b * n, d), jnp.float64),
-                _spec(one_chip, (b * n, d), jnp.float64),
+                _spec(sharding, (nodes, d), jnp.int32),
+                _spec(sharding, (nodes, d, reps), jnp.float64),
+                _spec(sharding, (nodes, d), jnp.float64),
             ),
-            _spec(one_chip, (b,), jnp.float64),
-            _spec(one_chip, (b,), jnp.float64),
-            _spec(one_chip, (b,), jnp.bool_),
-            _spec(one_chip, (), jnp.float64),
+            _spec(sharding, (b,), jnp.float64),
+            _spec(sharding, (b,), jnp.float64),
+            _spec(sharding, (b,), jnp.bool_),
+            _spec(sharding, (), jnp.float64),
         )
-        compiled = kbell._csr_bisect.lower(
+        return kbell._csr_bisect.lower(
             *args, n_actors=n, k_probes=kbell.DEFAULT_K_PROBES,
             max_steps=40, max_rounds=0, detect_deadlock=False,
         ).compile()
+
+
+@pytest.mark.parametrize("pack", sorted(ELL_PACKS))
+def test_csr_bisect_ell_compiles(one_chip, pack):
+    """The exact float64 solve of a per-row pack at the smoke run's
+    packs."""
+    b, n, d = ELL_PACKS[pack]
+    compiled = _compile_bisect(one_chip, b * n, d, 1, b, n)
     mem = compiled.memory_analysis()
     # the whole solve stays well inside one v5e's 16 GB of HBM
     assert mem.temp_size_in_bytes < 4 * 1024**3, mem
+
+
+@pytest.mark.parametrize("pack", sorted(SHARED_PACKS))
+def test_csr_bisect_shared_compiles(one_chip, pack):
+    """The solve of a shared-topology pack relaxes node-major: each edge
+    slot gathers one row of every replica's probe distances, not a
+    3-wide slice per (row, node)."""
+    b, n, d = SHARED_PACKS[pack]
+    compiled = _compile_bisect(one_chip, n, d, b, b, n)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4 * 1024**3, mem
+    widths = {int(sizes.split(",")[-1]) for sizes in re.findall(
+        r"slice_sizes=\{([0-9,]+)\}", compiled.as_text())}
+    # the pointer hops gather single elements; the relaxation gathers
+    # rows of B * K distances
+    assert widths - {1} == {b * kbell.DEFAULT_K_PROBES}, widths
 
 
 @pytest.mark.parametrize("shape", [(256, 128, 384), (128, 256, 128)])
