@@ -65,18 +65,14 @@ def mcr_howard(g: SDFG, *, eps: float = 1e-9, max_iter: int = 10_000) -> float:
     if ne == 0:
         return NEG_INF
 
-    # adjacency: outgoing edge ids per node
-    out: list[list[int]] = [[] for _ in range(n)]
-    for e in range(ne):
-        out[int(src[e])].append(e)
-
-    has_out = np.array([len(o) > 0 for o in out])
-    # nodes with no outgoing edge can't be on a cycle; give them a virtual
-    # self-loop of ratio -inf by excluding them from policies.
-    policy = np.full(n, -1, dtype=np.int64)
-    for v in range(n):
-        if out[v]:
-            policy[v] = out[v][0]
+    # the first policy takes each node's first outgoing edge; nodes with no
+    # outgoing edge can't be on a cycle; give them a virtual self-loop of
+    # ratio -inf by excluding them from policies.
+    edge_ids = np.arange(ne, dtype=np.int64)
+    first = np.full(n, ne, dtype=np.int64)
+    np.minimum.at(first, src, edge_ids)
+    has_out = first < ne
+    policy = np.where(has_out, first, -1)
 
     lam = np.full(n, NEG_INF)
     u = np.zeros(n)
@@ -87,34 +83,39 @@ def mcr_howard(g: SDFG, *, eps: float = 1e-9, max_iter: int = 10_000) -> float:
         if dead:
             return math.inf
         # ---- policy improvement ------------------------------------
-        changed = False
-        for e in range(ne):
-            x, y = int(src[e]), int(dst[e])
-            if policy[x] == -1 or lam[y] == NEG_INF:
-                continue
-            if lam[y] > lam[x] + eps:
-                policy[x] = e
-                changed = True
-            elif abs(lam[y] - lam[x]) <= eps:
-                cand = w[e] - lam[x] * m[e] + u[y]
-                if cand > u[x] + eps:
-                    policy[x] = e
-                    changed = True
-        if not changed:
+        # an edge improves its source when it leads to a higher ratio, or
+        # to the same ratio with a higher bias; lam and u are fixed here,
+        # so each node takes its last improving edge in edge order
+        lx, ly = lam[src], lam[dst]
+        with np.errstate(invalid="ignore"):
+            higher = ly > lx + eps
+            tied = np.abs(ly - lx) <= eps
+            cand = w - lx * m + u[dst]
+        better = (ly != NEG_INF) & (
+            higher | (tied & (cand > u[src] + eps)))
+        if not better.any():
             break
+        last = np.full(n, -1, dtype=np.int64)
+        np.maximum.at(last, src[better], edge_ids[better])
+        policy = np.where(last >= 0, last, policy)
     finite = lam[np.isfinite(lam)]
     return float(finite.max()) if finite.size else NEG_INF
 
 
 def _evaluate_policy(n, policy, src, dst, w, m, has_out):
     """Evaluate a policy (functional graph): per-node cycle ratio + bias."""
-    lam = np.full(n, NEG_INF)
-    u = np.zeros(n)
-    color = np.zeros(n, dtype=np.int8)  # 0 white 1 on-stack 2 done
-    dead = False
+    # the walk runs on Python lists: indexing numpy arrays one scalar at a
+    # time costs more than the arithmetic, which is float64 either way
+    succ = dst[policy].tolist()
+    pw = w[policy].tolist()
+    pm = m[policy].tolist()
+    live = has_out.tolist()
+    lam = [NEG_INF] * n
+    u = [0.0] * n
+    color = [0] * n  # 0 white 1 on-stack 2 done
 
     for start in range(n):
-        if color[start] != 0 or not has_out[start]:
+        if color[start] != 0 or not live[start]:
             color[start] = 2
             continue
         path: list[int] = []
@@ -122,18 +123,18 @@ def _evaluate_policy(n, policy, src, dst, w, m, has_out):
         while color[v] == 0:
             color[v] = 1
             path.append(v)
-            v = int(dst[policy[v]])
-            if not has_out[v]:
+            v = succ[v]
+            if not live[v]:
                 break
         if color[v] == 1:
             # found a new cycle: v .. path[-1]
-            ci = path.index(v)
-            cyc = path[ci:]
-            wsum = sum(w[policy[x]] for x in cyc)
-            msum = sum(m[policy[x]] for x in cyc)
+            cyc = path[path.index(v):]
+            wsum, msum = 0.0, 0
+            for x in cyc:       # left to right, as the cycle is walked
+                wsum += pw[x]
+                msum += pm[x]
             if msum == 0:
-                dead = True
-                return lam, u, dead
+                return np.array(lam), np.array(u), True
             ratio = wsum / msum
             for x in cyc:
                 lam[x] = ratio
@@ -141,23 +142,21 @@ def _evaluate_policy(n, policy, src, dst, w, m, has_out):
             # walk the cycle backwards so each successor is resolved first
             u[v] = 0.0
             for x in reversed(cyc[1:]):
-                y = int(dst[policy[x]])
-                u[x] = w[policy[x]] - ratio * m[policy[x]] + u[y]
+                u[x] = pw[x] - ratio * pm[x] + u[succ[x]]
         # resolve tree part (suffix of `path` before the cycle / known node)
         for x in reversed(path):
             if lam[x] != NEG_INF:
                 continue
-            y = int(dst[policy[x]])
+            y = succ[x]
             if lam[y] == NEG_INF:
-                lam[x] = NEG_INF  # leads nowhere cyclic
-                u[x] = 0.0
+                u[x] = 0.0  # leads nowhere cyclic
             else:
                 lam[x] = lam[y]
-                u[x] = w[policy[x]] - lam[x] * m[policy[x]] + u[y]
+                u[x] = pw[x] - lam[x] * pm[x] + u[y]
         for x in path:
             color[x] = 2
         color[v] = 2
-    return lam, u, dead
+    return np.array(lam), np.array(u), False
 
 
 # ======================================================================

@@ -135,7 +135,8 @@ class OptimizeReport:
     each generation's epsilon-non-dominated rows).  ``history`` records
     per-generation progress; ``n_stack_builds`` counts EdgeStack builds
     (= generations + 1: one per generation plus the final exact
-    re-score).
+    re-score).  ``n_rows`` counts the candidate rows scored over all of
+    them (final pool included, bucket padding not).
     """
 
     binding: np.ndarray                 # (n_clusters,) int64 tile ids
@@ -151,6 +152,7 @@ class OptimizeReport:
     energy: float = float("inf")        # pJ per iteration
     seed_energies: dict[str, float] = dataclasses.field(default_factory=dict)
     front: list[ParetoPoint] = dataclasses.field(default_factory=list)
+    n_rows: int = 0
 
     @property
     def throughput(self) -> float:
@@ -451,6 +453,7 @@ class _BindingSearch:
         # best-ever rows; re-ranked exactly at the end
         self.archive = seed_mat.copy()
         self.n_builds = 0
+        self.n_rows = 0
         self.gen = 0
         self.final_pool: Optional[np.ndarray] = None
         self._report: Optional[OptimizeReport] = None
@@ -473,6 +476,8 @@ class _BindingSearch:
         """Consume the scores of the last :meth:`ask` batch."""
         assert not self.done, "search already finalized"
         self.n_builds += 1
+        batch = self.pop if self.final_pool is None else self.final_pool
+        self.n_rows += batch.shape[0]
         if self.final_pool is not None:
             self._finalize(periods, energies)
             return
@@ -608,6 +613,7 @@ class _BindingSearch:
             energy=float(final_energies[best_row]),
             seed_energies=seed_energies,
             front=front,
+            n_rows=self.n_rows,
         )
 
     def report(self) -> OptimizeReport:

@@ -350,17 +350,20 @@ def runtime_admit(
         # binding — relabeled physically — seeds the final exact pool,
         # which makes the refined admission never worse than the plain one
         phys_seed = np.array([free[t] for t in virt_binding], dtype=np.int64)
-        phys_opt = optimize_binding(
-            clustered, state.hw,
-            single_order=single_order,
-            generations=gens, population=pop,
-            weights=weights, allowed_tiles=free,
-            extra_seeds=[phys_seed],
-            chip_state=chip_state, rate_scale=rate_scale,
-        ).binding
+        with obs.span("refine", generations=gens, population=pop) as sp:
+            opt = optimize_binding(
+                clustered, state.hw,
+                single_order=single_order,
+                generations=gens, population=pop,
+                weights=weights, allowed_tiles=free,
+                extra_seeds=[phys_seed],
+                chip_state=chip_state, rate_scale=rate_scale,
+            )
+            sp.attrs.update(rows=opt.n_rows, seed_period=opt.best_seed_period,
+                            period=opt.period)
         to_virt = {p: v for v, p in enumerate(free)}
         virt_binding = np.array(
-            [to_virt[int(t)] for t in phys_opt], dtype=np.int64
+            [to_virt[int(t)] for t in opt.binding], dtype=np.int64
         )
         refined = True
     t_bind = time.perf_counter() - t0

@@ -125,6 +125,34 @@ def test_power_iteration_converges_on_strongly_connected(seed):
         assert np.isclose(power, howard, rtol=1e-3), (use_kernel, power, howard)
 
 
+@pytest.mark.parametrize("seed", range(12))
+def test_howard_matches_brute_force_off_cycle_nodes(seed):
+    """Self-loops first, as every actor of a hardware-aware SDFG has, then
+    random edges without a ring: not strongly connected, so improved
+    policies hang tails into cycles that other starts found."""
+    rng = np.random.default_rng(500 + seed)
+    n = int(rng.integers(3, 7))
+    channels = [Channel(i, i, int(rng.integers(1, 3)), 1.0, kind="self")
+                for i in range(n)]
+    for _ in range(int(rng.integers(n, 3 * n))):
+        i, j = int(rng.integers(n)), int(rng.integers(n))
+        # a backward edge carries a token, so every cycle is live
+        tokens = int(rng.integers(1, 3)) if j <= i else int(rng.integers(0, 2))
+        channels.append(Channel(i, j, tokens, 1.0,
+                                delay=float(rng.uniform(0, 2.0))))
+    g = SDFG(n_actors=n, exec_time=rng.uniform(0.5, 5.0, size=n),
+             channels=channels)
+    exact = brute_force_mcr(g)
+    howard = mcr_howard(g)
+    assert np.isclose(howard, exact, rtol=1e-12), (howard, exact)
+
+
+def test_acyclic_graph_reports_minus_inf():
+    g = SDFG(n_actors=3, exec_time=np.array([1.0, 2.0, 3.0]),
+             channels=[Channel(0, 1, 0, 1.0), Channel(1, 2, 1, 1.0)])
+    assert mcr_howard(g) == -np.inf
+
+
 def test_deadlocked_graph_reports_inf():
     # 0 -> 1 -> 0 with no tokens anywhere on the cycle
     g = SDFG(
