@@ -45,6 +45,7 @@ from .hardware import ChipState, HardwareConfig
 from .maxplus import (
     NEG_INF,
     EdgeStack,
+    _bucket_size,
     _on_accelerator as _engine_on_accelerator,
     evolve_batch,
     maxplus_matrix_batch,
@@ -561,21 +562,6 @@ def stack_hardware_aware(
 # ======================================================================
 # shape-bucket compile cache: stable stacked shapes across admissions
 # ======================================================================
-def _bucket_size(x: int) -> int:
-    """Round up to the next pow2-ish bucket (1, 2, 3, 4, 6, 8, 12, 16, …).
-
-    Half-steps between powers of two keep the bucket within 2x of the
-    request (< 50% padding waste, vs the plain next-power-of-two's ~100%)
-    while collapsing the long tail of one-off shapes onto a few buckets.
-    """
-    if x <= 1:
-        return 1
-    p = 1 << (x - 1).bit_length()          # next power of two >= x
-    if x <= (3 * p) // 4:
-        return (3 * p) // 4
-    return p
-
-
 @dataclasses.dataclass
 class CompileCacheStats:
     """Shape-bucket reuse counters of the batched analysis layer.

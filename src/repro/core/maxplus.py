@@ -570,54 +570,127 @@ def _pack_csr_chunk(
     row subset independently is exact: the ELL width tracks the chunk's
     own in-degree profile and pad slots carry the ``-inf`` neutral
     element, so per-row results never depend on which rows share the
-    pack.  Two or more rows whose finite edges share one topology (the
-    candidates of one app) pack as replicas of one ELL
-    (:func:`_pack_shared`); any other stack packs row by row
-    (:func:`_pack_per_row`).  Both give the same slots in the same order,
-    so the solve's results and counts are the same in either layout.
+    pack.  One of three layouts, chosen from the stack alone
+    (:func:`_choose_layout`):
+
+    * ``"shared"``: every row with a finite edge has the same topology
+      (the subset candidates of one app); one ELL over it with the rows
+      as replicas (:func:`_pack_shared`);
+    * ``"split"``: the rows share a base of columns and differ in the
+      rest (the binding candidates of one app: one self, flow and buffer
+      base, per-row order edges); the base packs as ``"shared"`` does and
+      the rest as a per-replica ELL keyed by the same nodes
+      (:func:`_pack_shared` too);
+    * ``"per_row"``: any other stack, one ELL over every row's nodes
+      (:func:`_pack_per_row`).
+
+    Every layout gives each node's edges in the same slot order, so the
+    solve's results and counts are the same in any of them.
     """
-    ref = _shared_topology(stack)
-    if ref is None:
+    layout, ref, same = _choose_layout(stack)
+    if layout == "per_row":
         return _pack_per_row(stack, lo0)
-    return _pack_shared(stack, lo0, ref)
+    return _pack_shared(stack, lo0, ref, same)
 
 
-def _shared_topology(stack: EdgeStack) -> Optional[int]:
-    """Index of the first row with a finite edge, when the stack has two
-    or more rows and every row with a finite edge has the same finite
-    mask, ``src``, ``dst`` and ``tokens`` on it; else ``None``.  Rows with
-    no finite edge (all--inf padding) do not count."""
+def _choose_layout(
+    stack: EdgeStack,
+) -> tuple[str, Optional[int], Optional[np.ndarray]]:
+    """``(layout, ref, same)``: the ELL layout of :func:`_pack_csr_chunk`,
+    the first row with a finite edge, and the ``(E,)`` columns that pack
+    as the shared base.  Rows with no finite edge (all--inf padding) do
+    not count, and a stack of one row packs per row.
+
+    A column is shared when every counted row has row ``ref``'s finite
+    mask, ``src``, ``dst`` and ``tokens`` on it and, at its node, it
+    comes before every column that is not: each node's slots then keep
+    the column order of the per-row pack.  All columns shared:
+    ``"shared"``.  Else ``"split"`` when some finite column is shared
+    and the split gathers fewer slices a round than the per-row pack,
+    ``n*d0 + n*d1*B < B*n*d``; else ``"per_row"``.
+    """
     fin = np.isfinite(stack.weights)
     real = fin.any(axis=1)
     if stack.n_graphs < 2 or not real.any():
-        return None
+        return "per_row", None, None
     ref = int(np.argmax(real))
     same = fin[real] == fin[ref]
     for a in (stack.src, stack.dst, stack.tokens):
         same &= (a[real] == a[ref]) | ~fin[ref]
-    return ref if same.all() else None
+    same = same.all(axis=0)
+    if same.all():
+        return "shared", ref, same
+    b, (n, e) = stack.n_graphs, (stack.n_actors, stack.n_edges)
+    # a common column after a node's first column of its own (a shortcut
+    # that every row happens to share) goes with the per-row edges
+    rows, cols = np.nonzero(fin & ~same)
+    first = np.full(n, e)
+    np.minimum.at(first, stack.dst[rows, cols], cols)
+    same &= ~fin[ref] | (np.arange(e) < first[stack.dst[ref]])
+    base = same & fin[ref]
+    if not base.any():
+        return "per_row", ref, same
+    rows, cols = np.nonzero(fin & ~same)
+    d0 = _pow2(np.bincount(stack.dst[ref, base]).max())
+    d1 = _bucket_size(int(np.bincount(rows * n + stack.dst[rows, cols]).max()))
+    rows, cols = np.nonzero(fin)
+    d = _pow2(np.bincount(rows * n + stack.dst[rows, cols]).max())
+    if n * d0 + n * d1 * b < b * n * d:
+        return "split", ref, same
+    return "per_row", ref, same
 
 
 def _pack_shared(
-    stack: EdgeStack, lo0: Optional[np.ndarray], ref: int
+    stack: EdgeStack, lo0: Optional[np.ndarray], ref: int, same: np.ndarray
 ) -> tuple:
-    """:func:`_pack_csr_chunk` for rows that share row ``ref``'s topology:
-    one ELL over ``n`` nodes with local ids, ``ell_w`` ``(n, d, B)``."""
+    """:func:`_pack_csr_chunk` for rows that share row ``ref``'s finite
+    ``same`` columns: one ELL over ``n`` nodes with local ids,
+    ``ell_w`` ``(n, d0, B)``.  The rows' other finite columns, when there
+    are any (the split layout), pack per replica at the same nodes in
+    column order: sources, weights and tokens each ``(n, d1, B)``, ``d1``
+    on a shape bucket (:func:`_bucket_size`)."""
     b, n = stack.n_graphs, stack.n_actors
-    keep = np.isfinite(stack.weights[ref])
+    fin = np.isfinite(stack.weights)
+    keep = same & fin[ref]
     dst = stack.dst[ref, keep]
     order = np.argsort(dst, kind="stable")
     uniq_keys, seg_starts = np.unique(dst[order], return_index=True)
     w_ord = stack.weights[:, keep][:, order]          # (B, E'); pads -inf
     max_in = np.full((b, n), NEG_INF)
     max_in[:, uniq_keys] = np.maximum.reduceat(w_ord, seg_starts, axis=1)
-    upper = np.clip(max_in, 0.0, None).sum(axis=1)
-    lo, hi, has_cycle = _bisection_bounds(stack, upper, lo0)
     operands = _ell_pack(
         stack.src[ref, keep][order], dst[order], w_ord.T,
         stack.tokens[ref, keep][order].astype(np.float64), n,
         uniq_keys, seg_starts,
     )
+    rows, cols = np.nonzero(fin & ~same)
+    if rows.size:
+        # keyed node-major, replica minor; the stable sort keeps each
+        # (node, replica)'s edges in column order
+        key = stack.dst[rows, cols] * b + rows
+        order = np.argsort(key, kind="stable")
+        rows, cols, key = rows[order], cols[order], key[order]
+        uniq_keys, seg_starts = np.unique(key, return_index=True)
+        w_ord = stack.weights[rows, cols]
+        max_rep = np.full(n * b, NEG_INF)
+        max_rep[uniq_keys] = np.maximum.reduceat(w_ord, seg_starts)
+        max_in = np.maximum(max_in, max_rep.reshape(n, b).T)
+        counts = np.diff(np.append(seg_starts, key.size))
+        d1 = _bucket_size(int(counts.max()))
+        pos = np.arange(key.size) - np.repeat(seg_starts, counts)
+        rep_src = np.zeros((n * b, d1), dtype=np.int32)
+        rep_w = np.full((n * b, d1), NEG_INF)
+        rep_t = np.zeros((n * b, d1))
+        rep_src[key, pos] = stack.src[rows, cols]
+        rep_w[key, pos] = w_ord
+        rep_t[key, pos] = stack.tokens[rows, cols]
+        operands += tuple(np.ascontiguousarray(a.reshape(n, b, d1)
+                                               .transpose(0, 2, 1))
+                          for a in (rep_src, rep_w, rep_t))
+    # the per-row simple-path bound: the same maxima as _pack_per_row's,
+    # summed over the same (B, n) array
+    upper = np.clip(max_in, 0.0, None).sum(axis=1)
+    lo, hi, has_cycle = _bisection_bounds(stack, upper, lo0)
     return operands, lo, hi, has_cycle
 
 
@@ -769,6 +842,26 @@ def _mcr_batch_csr(
     return np.where(dead, np.inf, res) if detect_deadlock else res
 
 
+def _pow2(x: int) -> int:
+    """The next power of two ``>= x`` (``>= 1``): the ELL width bucket."""
+    return 1 << (max(int(x), 1) - 1).bit_length()
+
+
+def _bucket_size(x: int) -> int:
+    """Round up to the next pow2-ish bucket (1, 2, 3, 4, 6, 8, 12, 16, …).
+
+    Half-steps between powers of two keep the bucket within 2x of the
+    request (< 50% padding waste, vs the plain next-power-of-two's ~100%)
+    while collapsing the long tail of one-off shapes onto a few buckets.
+    """
+    if x <= 1:
+        return 1
+    p = 1 << (x - 1).bit_length()          # next power of two >= x
+    if x <= (3 * p) // 4:
+        return (3 * p) // 4
+    return p
+
+
 def _ell_pack(
     src_ord: np.ndarray,
     dst_ord: np.ndarray,
@@ -788,8 +881,7 @@ def _ell_pack(
     in-degree profile crosses a bucket, not on every edge-count wiggle.
     """
     counts = np.diff(np.append(seg_starts, src_ord.size))
-    d_max = int(counts.max(initial=1))
-    d_max = 1 << (d_max - 1).bit_length()
+    d_max = _pow2(int(counts.max(initial=1)))
     pos = np.arange(src_ord.size) - np.repeat(seg_starts, counts)
     row_idx = dst_ord
     ell_src = np.zeros((n_keys, d_max), dtype=np.int32)

@@ -28,11 +28,16 @@ per-round segment fold becomes a dense ``dist[ell_src] + ww`` gather and
 a ``max`` over the degree axis.  No scatter anywhere; this is what XLA
 vectorizes well on CPU and TPU alike (the scatter-based ``segment_max``
 lowering costs several times a numpy ``reduceat`` per round on CPU).
-When the stack's rows share one topology and differ only in weights (the
-candidate bindings of one app), the ELL is built once over that topology
-and each node carries every row as a replica: the gather then fetches one
-lane-dense row of ``B*K`` distances per edge slot, where a per-row pack
-fetches ``B*n*d`` rows of ``K``, each padded to a full vector tile.
+The pack takes one of three layouts.  When the stack's rows share one
+topology and differ only in weights (the subset candidates of one app),
+the ELL is built once over that topology and each node carries every row
+as a replica: the gather then fetches one lane-dense row of ``B*K``
+distances per edge slot, where a per-row pack fetches ``B*n*d`` rows of
+``K``, each padded to a full vector tile.  When the rows share a base of
+edges and differ in a few more per node (the binding candidates of one
+app: shared self, flow and buffer edges, per-row order edges), the base
+relaxes as a shared ELL and the rest as a small per-replica ELL at the
+same nodes, ``n*d1*B`` rows of ``K``.  Any other stack packs row by row.
 
 Everything here is float64 (``jax.enable_x64(True)`` scoped to these
 calls; XLA:TPU emulates it with float32 pairs): the bisection must
@@ -80,7 +85,8 @@ def _follow(ptr, idx):
 
 
 def csr_bisect(
-    operands,       # (ell_src (N, d), ell_w (N, d, R), ell_t (N, d))
+    operands,       # (ell_src (N, d), ell_w (N, d, R), ell_t (N, d)[,
+                    #  rep_src, rep_w, rep_t, each (N, d1, R)])
     lo,             # (B,) float64 sound lower bounds
     hi,             # (B,) float64 interval tops (> any finite cycle ratio)
     has_cycle,      # (B,) bool rows already known cyclic
@@ -109,6 +115,12 @@ def csr_bisect(
     topology packs as ``R = B, N = n``.  Distances are node-major,
     ``(N, R*k)`` with column ``r*k + j`` for replica ``r``'s probe ``j``,
     so a relaxation gathers one row of ``R*k`` distances per edge slot.
+    A split pack (``R = B, N = n``) adds three per-replica operands:
+    replica ``r``'s own incoming edges at node ``v`` sit in
+    ``rep_*[v, :, r]``, and each gathers the ``k`` distances of its
+    source's replica ``r``.  Every round takes the max over both groups,
+    and the witness looks at the shared slots first, then the
+    per-replica ones.
 
     ``counts`` is an int32 ``(3,)`` array that the loops carry beside the
     search and that feeds nothing back into it: the outer bisection steps;
@@ -117,7 +129,8 @@ def csr_bisect(
     blocks, the (row, probe) pairs not yet resolved when the block began,
     counting rows with a finite edge only (all--inf pad rows left out).
     """
-    ell_src, ell_w, ell_t = operands
+    ell_src, ell_w, ell_t = operands[:3]
+    rep = operands[3:]                     # split layout only
     b = lo.shape[0]
     n_nodes, _, reps = ell_w.shape
     groups = b // reps                     # = n_nodes // n_actors
@@ -126,8 +139,11 @@ def csr_bisect(
     n_doublings = max(1, (n_actors + 1).bit_length())
     upper = hi - 1.0                       # host invariant: hi = upper' + 1
     ids = jnp.arange(n_nodes, dtype=jnp.int32)[:, None]
-    slot = jnp.arange(ell_src.shape[1], dtype=jnp.int32)
+    slots = ell_src.shape[1] + (rep[0].shape[1] if rep else 0)
+    slot = jnp.arange(slots, dtype=jnp.int32)
     real = jnp.isfinite(ell_w).reshape(groups, -1, reps).any(axis=1)
+    if rep:
+        real = real | jnp.isfinite(rep[1]).any(axis=(0, 1))  # groups == 1
     real = real.reshape(b, 1)
 
     def per_row(x, k):
@@ -145,17 +161,37 @@ def csr_bisect(
         def cand(dist):
             return dist[ell_src] + (w_col - lam_col * ell_t[:, :, None])
 
+        if rep:
+            # split layout: replica r's own edges gather the k distances
+            # of its source's replica r, rows of dist viewed as (N*R, k)
+            rep_src, rep_w, rep_t = (jnp.repeat(a, k, axis=2) for a in rep)
+            rep_row = rep[0] * reps + jnp.arange(reps, dtype=jnp.int32)
+
+            def cand_rep(dist):
+                c = dist.reshape(-1, k)[rep_row].reshape(rep_src.shape)
+                return c + (rep_w - lam_col * rep_t)
+
         def best_of(dist):
-            return cand(dist).max(axis=1)
+            best = cand(dist).max(axis=1)
+            if rep:
+                best = jnp.maximum(best, cand_rep(dist).max(axis=1))
+            return best
 
         def witness(dist):
+            # slots in column order: the shared ones, then the replica's
             c = cand(dist)
+            if rep:
+                c = jnp.concatenate([c, cand_rep(dist)], axis=1)
             amax = c.argmax(axis=1)                         # (N, R*k)
             # one-hot select of the argmax source: the same values as
             # take_along_axis, whose per-row gather takes the TPU
             # compiler ~35 s at 1e5 rows (this form: ~1 s)
             hit = slot[None, :, None] == amax[:, None, :]
-            psrc = jnp.where(hit, ell_src[:, :, None], 0).sum(axis=1)
+            src = ell_src[:, :, None]
+            if rep:
+                src = jnp.concatenate([jnp.broadcast_to(
+                    src, (*ell_src.shape, reps * k)), rep_src], axis=1)
+            psrc = jnp.where(hit, src, 0).sum(axis=1)
             return c.max(axis=1), psrc
 
         return best_of, witness
@@ -300,9 +336,9 @@ def _dispatch_bisect(
         return jax.device_put(arr, device) if device is not None \
             else jnp.asarray(arr)
 
-    ell_src, ell_w, ell_t = operands
-    ops_dev = (put(ell_src, np.int32), put(ell_w, np.float64),
-               put(ell_t, np.float64))
+    # (src, w, t) of the ELL, then of the per-replica ELL when split
+    ops_dev = tuple(put(x, np.int32 if i % 3 == 0 else np.float64)
+                    for i, x in enumerate(operands))
     return _csr_bisect(
         ops_dev,
         put(lo, np.float64),
@@ -325,13 +361,16 @@ def _tally(counts, operands, b: int, k_probes: int) -> dict:
     relaxed, over the rows with a finite edge; ``live_probe_rounds``: the
     ones among them not yet resolved; ``relaxations``: single edge
     relaxations, ``(B*n) * d * K`` a round, pad rows and slots included,
-    in either layout.
+    with ``d = d0 + d1`` in the split layout.
     """
     steps, blocks, live = (int(x) for x in np.asarray(counts))
-    _, ell_w, _ = operands
-    nodes, d, reps = np.shape(ell_w)
-    rows = int(np.isfinite(ell_w).reshape(b // reps, -1, reps)
-               .any(axis=1).sum())
+    weights = operands[1::3]               # ell_w, and rep_w when split
+    nodes, _, reps = np.shape(weights[0])
+    d = sum(np.shape(w)[1] for w in weights)
+    real = np.zeros((b // reps, reps), dtype=bool)
+    for w in weights:
+        real |= np.isfinite(w).reshape(b // reps, -1, reps).any(axis=1)
+    rows = int(real.sum())
     rounds = CHECK_EVERY * blocks
     return {
         "steps": steps,
@@ -343,24 +382,29 @@ def _tally(counts, operands, b: int, k_probes: int) -> dict:
 
 
 def _layout(operand_sets) -> str:
-    """``"shared"`` when every chunk's rows share one topology (``R > 1``
-    replicas per ELL node), else ``"per_row"``."""
-    shared = all(np.shape(ops[1])[2] > 1 for ops in operand_sets)
-    return "shared" if shared else "per_row"
+    """A solve's layout, over the chunks it packed: ``"per_row"`` when a
+    chunk packs row by row (one replica per ELL node), else ``"split"``
+    when a chunk carries per-replica operands, else ``"shared"``."""
+    if any(np.shape(ops[1])[2] == 1 for ops in operand_sets):
+        return "per_row"
+    if any(len(ops) > 3 for ops in operand_sets):
+        return "split"
+    return "shared"
 
 
 def _record(span, tallies: list) -> None:
-    """Adds one solve's counters (``solve.calls``, ``solve.shared_calls``
-    and ``solve.<key>`` of :func:`_tally`) to the attached recorder and
-    puts them on the solve's ``device_solve`` span, beside its ``layout``.
-    The chunks of a sharded solve run side by side on their devices, so
-    its steps and rounds are those of its longest chunk; its pairs and
-    relaxations add up over the chunks."""
+    """Adds one solve's counters (``solve.calls``, ``solve.shared_calls``,
+    ``solve.split_calls`` and ``solve.<key>`` of :func:`_tally`) to the
+    attached recorder and puts them on the solve's ``device_solve`` span,
+    beside its ``layout``.  The chunks of a sharded solve run side by side
+    on their devices, so its steps and rounds are those of its longest
+    chunk; its pairs and relaxations add up over the chunks."""
     total = {key: (max if key in ("steps", "rounds") else sum)(
         t[key] for t in tallies) for key in tallies[0]}
     span.attrs.update(total)
     obs.count("solve.calls")
     obs.count("solve.shared_calls", int(span.attrs["layout"] == "shared"))
+    obs.count("solve.split_calls", int(span.attrs["layout"] == "split"))
     for key, n in total.items():
         obs.count(f"solve.{key}", n)
 
@@ -379,7 +423,8 @@ def mcr_bisect_device(
     """Host-facing entry: numpy CSR/ELL arrays in, numpy results out.
 
     ``operands`` is ``(ell_src, ell_w, ell_t)``, shaped ``(N, d)``,
-    ``(N, d, R)`` and ``(N, d)`` (:func:`csr_bisect`).
+    ``(N, d, R)`` and ``(N, d)``, and in the split layout three
+    per-replica arrays more, each ``(N, d1, R)`` (:func:`csr_bisect`).
     Scopes ``jax.enable_x64(True)`` around conversion, tracing and
     execution so the bisection runs in float64 without flipping the
     process-global jax precision (the Pallas semiring kernels stay
@@ -388,7 +433,7 @@ def mcr_bisect_device(
     device.
 
     The call is the program's ``device_solve`` span; its ``layout`` attr
-    says whether the rows shared one topology.  While a recorder is
+    says how the rows were packed (:func:`_layout`).  While a recorder is
     attached (:func:`repro.obs.recording`) the solve's loop counts come
     back from the device and are recorded (:func:`_record`); else they
     never leave it.

@@ -138,8 +138,11 @@ def test_refine_counters_count_the_real_rows_of_per_row_solves(runs):
     assert sp.attrs["rows"] == real
     assert counters["solve.calls"] == len(solves) == BUDGET[0] + 1
     assert counters["solve.shared_calls"] == 0
+    # the candidates share the app's self, flow and buffer edges and
+    # differ in their order edges: every solve packs split
+    assert counters["solve.split_calls"] == counters["solve.calls"]
     dev = [s for s in run["rec"].spans if s.name == "device_solve"]
-    assert {s.attrs["layout"] for s in dev} == {"per_row"}
+    assert {s.attrs["layout"] for s in dev} == {"split"}
     assert all(s.request == sp.request and s.start_ns >= sp.start_ns
                and s.end_ns <= sp.end_ns for s in dev)
 

@@ -91,6 +91,7 @@ def test_recorded_counters_follow_the_loop_counts():
     assert c == {
         "solve.calls": 1,
         "solve.shared_calls": 1,
+        "solve.split_calls": 0,
         "solve.steps": steps,
         "solve.rounds": rounds,
         "solve.probe_rounds": rounds * len(lo) * K,
@@ -103,7 +104,81 @@ def test_recorded_counters_follow_the_loop_counts():
     assert rec.spans[1].attrs == {
         "layout": "shared",
         **{k[len("solve."):]: v for k, v in c.items()
-           if k not in ("solve.calls", "solve.shared_calls")}}
+           if k not in ("solve.calls", "solve.shared_calls",
+                        "solve.split_calls")}}
+
+
+def _split_stack(b=4, n=12):
+    """Rings of ``n`` actors with self-loops, the same in every row, and
+    one more in-edge a node that differs from row to row: the base of a
+    split pack and its per-replica edges."""
+    rng = np.random.default_rng(17)
+    ring = np.arange(n)
+    src = np.concatenate([np.broadcast_to(ring, (b, n)),
+                          np.broadcast_to(ring, (b, n)),
+                          np.argsort(rng.random((b, n)), axis=1)], axis=1)
+    dst = np.concatenate([np.broadcast_to(ring, (b, n)),
+                          np.broadcast_to((ring + 1) % n, (b, n)),
+                          np.broadcast_to(ring, (b, n))], axis=1)
+    tokens = np.concatenate([np.ones((b, n), int),
+                             np.broadcast_to(ring == n - 1, (b, n)),
+                             np.ones((b, n), int)], axis=1).astype(np.int64)
+    return mp.EdgeStack(n_actors=n, src=src, dst=dst, tokens=tokens,
+                        weights=rng.uniform(0.5, 2.0, src.shape))
+
+
+def test_split_counters_count_both_groups():
+    stack = _split_stack()
+    operands, lo, _, _ = mp._pack_csr_chunk(stack, None)
+    assert len(operands) == 6
+    d0, d1 = operands[1].shape[1], operands[4].shape[1]
+    steps_cap = max(4, int(math.ceil(80 / math.log2(K + 1))) + 1)
+    steps, blocks, live = counts(stack, max_steps=steps_cap)
+    with obs.recording() as rec:
+        mp._mcr_batch_csr(stack)
+    rounds = kbell.CHECK_EVERY * blocks
+    assert rec.counters == {
+        "solve.calls": 1,
+        "solve.shared_calls": 0,
+        "solve.split_calls": 1,
+        "solve.steps": steps,
+        "solve.rounds": rounds,
+        "solve.probe_rounds": rounds * len(lo) * K,
+        "solve.live_probe_rounds": kbell.CHECK_EVERY * live,
+        "solve.relaxations": (rounds * stack.n_actors * (d0 + d1)
+                              * len(lo) * K),
+    }
+    span = next(s for s in rec.spans if s.name == "device_solve")
+    assert span.attrs["layout"] == "split"
+
+
+def test_layout_of_a_solve_over_its_chunks():
+    """A sharded solve is as shared as its least shared chunk."""
+    per_row = mp._pack_per_row(_split_stack(), None)[0]
+    shared = mp._pack_csr_chunk(_ring_stack(3, 16, 1, shortcuts=True),
+                                None)[0]
+    split = mp._pack_csr_chunk(_split_stack(), None)[0]
+    assert kbell._layout([shared, shared]) == "shared"
+    assert kbell._layout([split, shared]) == "split"
+    assert kbell._layout([split, split]) == "split"
+    assert kbell._layout([split, per_row]) == "per_row"
+    assert kbell._layout([per_row]) == "per_row"
+
+
+def test_sharded_split_solve_counts_one_split_call():
+    stack = _split_stack(b=6)
+    dev = jax.devices()[0]
+    with obs.recording() as one:
+        a = mp._mcr_batch_csr(stack)
+    with obs.recording() as two:
+        b = mp._mcr_batch_csr(stack, devices=[dev, dev])
+    np.testing.assert_array_equal(a, b)
+    for rec in (one, two):
+        assert rec.counters["solve.calls"] == 1
+        assert rec.counters["solve.split_calls"] == 1
+        assert rec.counters["solve.shared_calls"] == 0
+        span = next(s for s in rec.spans if s.name == "device_solve")
+        assert span.attrs["layout"] == "split"
 
 
 def _pad(stack, rows):

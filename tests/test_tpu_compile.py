@@ -32,6 +32,11 @@ ELL_PACKS = {"burst": (64, 192, 64), "table1": (96, 1024, 64)}
 #: 64 candidate bindings of HeartClass and of LeNet-CIFAR
 SHARED_PACKS = {"heartclass": (64, 1024, 32), "lenet_cifar": (64, 768, 64)}
 
+#: (B, n, d0, d1) of the split packs of the binding refinements: eight
+#: candidate bindings of HeartClass (per-replica in-degree 9, on the
+#: bucket 12) and of LeNet-CIFAR (8) on four tiles
+SPLIT_PACKS = {"heartclass": (8, 1024, 32, 12), "lenet_cifar": (8, 768, 32, 8)}
+
 
 @pytest.fixture(scope="module")
 def one_chip():
@@ -63,16 +68,22 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _compile_bisect(sharding, nodes, d, reps, b, n):
+def _compile_bisect(sharding, nodes, d, reps, b, n, d1=0):
     """``_csr_bisect`` as ``mcr_bisect_device`` dispatches it (inside a
     ``jax.enable_x64`` scope), for ELL operands of ``nodes`` nodes with
-    ``reps`` replicas each."""
+    ``reps`` replicas each, and ``d1`` per-replica slots when split."""
     with jax.enable_x64(True):
+        per_replica = (
+            _spec(sharding, (nodes, d1, reps), jnp.int32),
+            _spec(sharding, (nodes, d1, reps), jnp.float64),
+            _spec(sharding, (nodes, d1, reps), jnp.float64),
+        ) if d1 else ()
         args = (
             (
                 _spec(sharding, (nodes, d), jnp.int32),
                 _spec(sharding, (nodes, d, reps), jnp.float64),
                 _spec(sharding, (nodes, d), jnp.float64),
+                *per_replica,
             ),
             _spec(sharding, (b,), jnp.float64),
             _spec(sharding, (b,), jnp.float64),
@@ -110,6 +121,21 @@ def test_csr_bisect_shared_compiles(one_chip, pack):
     # the pointer hops gather single elements; the relaxation gathers
     # rows of B * K distances
     assert widths - {1} == {b * kbell.DEFAULT_K_PROBES}, widths
+
+
+@pytest.mark.parametrize("pack", sorted(SPLIT_PACKS))
+def test_csr_bisect_split_compiles(one_chip, pack):
+    """The solve of a split pack gathers one row of every replica's probe
+    distances per shared slot, and ``K`` distances per replica's own
+    slot: no per-row gather over ``B*n`` nodes."""
+    b, n, d0, d1 = SPLIT_PACKS[pack]
+    compiled = _compile_bisect(one_chip, n, d0, b, b, n, d1)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4 * 1024**3, mem
+    k = kbell.DEFAULT_K_PROBES
+    widths = {int(sizes.split(",")[-1]) for sizes in re.findall(
+        r"slice_sizes=\{([0-9,]+)\}", compiled.as_text())}
+    assert widths - {1} == {b * k, k}, widths
 
 
 @pytest.mark.parametrize("shape", [(256, 128, 384), (128, 256, 128)])
