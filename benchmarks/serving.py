@@ -24,16 +24,7 @@ coalescing counters, and the burst speedup over baseline.  Acceptance:
 burst admissions/s beats the stored pre-refactor burst baseline
 (10.716/s on the reference host) with ``never_regressed`` true.
 
-``--devices N`` adds the device-scaling sweep: for each count ``d`` up
-to ``N`` the burst mode is drained again, in this process, with a
-``host_mesh(d)`` scoring mesh over the first ``d`` visible devices on
-the controller, so every rebalance's population scoring is sharded d
-ways.  ``N`` devices must be visible: the chips of an accelerator host,
-or on a CPU host ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-set for the whole process before it starts.  Per-arm trajectories are
-bit-identical by the ``mesh=`` contract — the sweep varies wall-clock
-only.  A separate
-speculative pre-compilation bench (cold controller, the same churn
+A separate speculative pre-compilation bench (cold controller, the same churn
 drained in waves through a :class:`~repro.core.serving.PrecompilePool`)
 reports the cache-warm-hit-rate.
 """
@@ -84,13 +75,12 @@ def _never_regressed(events) -> bool:
     return ok
 
 
-def make_controller(hw, joint_budget, mesh=None):
+def make_controller(hw, joint_budget):
     return AdmissionController(
         hw,
         placement="joint",
         joint_budget=joint_budget,
         full_rebalance_every=0,
-        mesh=mesh,
     )
 
 
@@ -199,8 +189,7 @@ def _precompile_bench(
     the :class:`PrecompilePool`'s frequency-decayed predictions (design
     artifacts + scoring shape buckets), so admissions of recurring
     tenants find their design work already done.  Reports the pool's
-    hit/miss accounting — ``hit_rate`` is the cache-warm-hit-rate stat
-    of the device-scaling section.
+    hit/miss accounting — ``hit_rate`` is the cache-warm-hit-rate.
     """
     ctl = make_controller(hw, joint_budget)
     pool = PrecompilePool(
@@ -238,7 +227,6 @@ def serving_bench(
     joint_budget: tuple[int, int] = (1, 6),
     coalesce_window: int = 16,
     seed: int = 0,
-    devices: int = 0,
 ):
     """Run both modes over the same churn; return ``(rows, payload, ok)``."""
     t0 = time.perf_counter()
@@ -265,15 +253,6 @@ def serving_bench(
         joint_budget=joint_budget, coalesce_window=coalesce_window,
     )
 
-    # device-scaling sweep over the visible devices, in this process
-    device_scaling = None
-    if devices > 0:
-        device_scaling = _device_sweep(
-            devices, hw, stream, requests, design_ctl.artifacts,
-            joint_budget=joint_budget, coalesce_window=coalesce_window,
-        )
-        device_scaling["cache_warm_hit_rate"] = precompile["hit_rate"]
-
     speedup = (
         burst["admissions_per_s"] / baseline["admissions_per_s"]
         if baseline["admissions_per_s"] > 0 else 0.0
@@ -288,7 +267,6 @@ def serving_bench(
         and burst["drained"]
         and beats_stored
         and precompile["drained"]
-        and (device_scaling is None or device_scaling["sweep_ok"])
     )
     summary = {
         "mesh": list(hw.mesh_shape),
@@ -308,8 +286,6 @@ def serving_bench(
         "beats_stored_baseline": beats_stored,
         "ok": ok,
     }
-    if device_scaling is not None:
-        summary["device_scaling"] = device_scaling
     rows = [
         ("mode", "events", "admits", "event_loop_s", "admissions_per_s",
          "never_regressed"),
@@ -320,64 +296,7 @@ def serving_bench(
          burst["event_loop_s"], burst["admissions_per_s"],
          burst["never_regressed"]),
     ]
-    if device_scaling is not None:
-        for d, aps in zip(device_scaling["device_counts"],
-                          device_scaling["admissions_per_s"]):
-            rows.append((f"burst@{d}dev", n_events, "-", "-", aps, "-"))
     return rows, summary, ok
-
-
-def _device_counts(n: int) -> list[int]:
-    """1 plus powers of two up to ``n`` (always ending at ``n``)."""
-    return sorted({1} | {d for d in (2, 4, 8, 16) if d <= n} | {int(n)})
-
-
-def _device_sweep(
-    n_devices: int, hw, stream, requests, artifacts, *,
-    joint_budget, coalesce_window,
-) -> dict:
-    """Admissions/s vs scoring-mesh device count, all arms in this process.
-
-    Each arm drains the same burst on a fresh controller that shares the
-    design cache; ``d == 1`` runs unsharded.  ``host_mesh(d)`` raises
-    when fewer than ``d`` devices are visible, so a short host fails the
-    run instead of measuring a smaller mesh.
-    """
-    from repro.launch.sharding import host_mesh
-
-    counts = _device_counts(n_devices)
-    arms = []
-    for d in counts:
-        ctl = make_controller(
-            hw, joint_budget, mesh=host_mesh(d) if d > 1 else None
-        )
-        ctl.artifacts = artifacts
-        burst = run_burst(
-            ctl, stream, requests, coalesce_window=coalesce_window
-        )
-        arms.append({
-            "devices": d,
-            "admissions_per_s": burst["admissions_per_s"],
-            "event_loop_s": burst["event_loop_s"],
-            "admitted": burst["service"]["admitted"],
-            "drained": burst["drained"],
-            "never_regressed": burst["never_regressed"],
-        })
-    aps = [a["admissions_per_s"] for a in arms]
-    base = aps[0] if aps[0] > 0 else 0.0
-    # 5% tolerance absorbs wall-clock noise on shared CI hosts
-    monotonic = all(b >= a * 0.95 for a, b in zip(aps, aps[1:]))
-    speedup = round(aps[-1] / base, 3) if base else 0.0
-    return {
-        "device_counts": counts,
-        "admissions_per_s": aps,
-        "monotonic_nondecreasing": monotonic,
-        "speedup_at_max_devices": speedup,
-        "target_speedup": 1.5,
-        "target_met": bool(base and speedup >= 1.5),
-        "sweep_ok": all(a["drained"] and a["never_regressed"] for a in arms),
-        "arms": arms,
-    }
 
 
 def run(out_path: str = "BENCH_serving.json", *, smoke: bool = False,
@@ -399,14 +318,11 @@ def main() -> None:
     ap.add_argument("--scale", type=float, default=0.06)
     ap.add_argument("--window", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--devices", type=int, default=0,
-                    help="device-scaling sweep up to N visible devices")
     args = ap.parse_args()
     rows, summary, ok = run(
         args.out, smoke=args.smoke, n_tenants=args.tenants,
         n_events=args.events, scale=args.scale,
         coalesce_window=args.window, seed=args.seed,
-        devices=args.devices,
     )
     for row in rows:
         print(",".join(str(x) for x in row))

@@ -2,7 +2,6 @@
 """Smoke run of the main path on a TPU, through the entry points a user calls.
 
   python chip_smoke.py              # phases (a)-(d) on one chip
-  python chip_smoke.py --chips 4    # the sharded solve only, on four chips
 
 (a) Device check: the first jax device must be a TPU; the Pallas (max,+)
     kernels and a small exact solve must match their oracles on it.
@@ -17,11 +16,6 @@
     the numpy ``"edges"`` oracle and with ``"csr-jit"`` (max relative
     error <= 1e-6), the cached chip metrics must match the exact
     full-union ones, and every device solve must have run on the TPU.
-
-``--chips 4`` drains the burst of (c) once with a ``host_mesh(4)``
-scoring mesh and once on one device, in this one process, and checks
-that the two trajectories are bit-identical and that every sharded
-solve put its four chunks on four distinct devices.
 
 Everything is generated from seeds.  Wall times include compilation:
 this is a smoke run, not a benchmark.  The last line of stdout is
@@ -63,48 +57,31 @@ class DispatchLog:
     """Devices of every exact device solve, one entry per solve call.
 
     Wraps the ``repro.kernels.maxplus_bellman`` entry points the analysis
-    layer calls: each ``mcr_bisect_device`` / ``mcr_bisect_device_sharded``
-    call opens an entry, and each chunk it dispatches appends the device
-    its result arrays live on.
+    layer calls: each ``mcr_bisect_device`` call opens an entry, and its
+    dispatch appends the device its result arrays live on.
     """
 
     def __init__(self):
         from repro.kernels import maxplus_bellman as kbell
 
-        self.calls: list[tuple[str, list]] = []
+        self.calls: list[list] = []
         dispatch = kbell._dispatch_bisect
+        solve = kbell.mcr_bisect_device
 
         def recording_dispatch(*args, **kw):
             out = dispatch(*args, **kw)
-            self.calls[-1][1].extend(out[0].devices())
+            self.calls[-1].extend(out[0].devices())
             return out
 
-        def opening(kind, fn):
-            def wrapped(*args, **kw):
-                self.calls.append((kind, []))
-                return fn(*args, **kw)
-            return wrapped
+        def opening(*args, **kw):
+            self.calls.append([])
+            return solve(*args, **kw)
 
         kbell._dispatch_bisect = recording_dispatch
-        kbell.mcr_bisect_device = opening("single", kbell.mcr_bisect_device)
-        kbell.mcr_bisect_device_sharded = opening(
-            "sharded", kbell.mcr_bisect_device_sharded
-        )
+        kbell.mcr_bisect_device = opening
 
     def devices(self) -> list:
-        return [d for _, devs in self.calls for d in devs]
-
-
-def trajectory(ctl) -> list:
-    """Everything of a controller's trajectory except wall-clock times."""
-    events = [
-        (e.kind, e.app, tuple(e.tiles), e.throughput, e.chip_throughput,
-         e.chip_energy, e.scope, e.region_apps,
-         tuple(sorted(e.app_throughputs.items())))
-        for e in ctl.events
-    ]
-    bindings = {n: r.binding.tolist() for n, r in sorted(ctl.reports.items())}
-    return [events, bindings]
+        return [d for devs in self.calls for d in devs]
 
 
 # ----------------------------------------------------------------------
@@ -151,12 +128,12 @@ def phase_designs():
     return ctl
 
 
-def drain_burst(mesh=None):
+def drain_burst():
     """(controller, burst result) of the full burst on a fresh controller."""
     from benchmarks.serving import build_workload, make_controller, run_burst
 
     hw, _, stream, requests, design_ctl, _, _ = build_workload(**BURST)
-    ctl = make_controller(hw, BURST["joint_budget"], mesh=mesh)
+    ctl = make_controller(hw, BURST["joint_budget"])
     ctl.artifacts = design_ctl.artifacts
     burst = run_burst(ctl, stream, requests, coalesce_window=WINDOW)
     return ctl, burst
@@ -274,44 +251,9 @@ def phase_reference(chips: dict, log: DispatchLog) -> None:
     print(f"max_rel_err_vs_edges={worst!r}", flush=True)
 
 
-def phase_sharded(log: DispatchLog, n: int) -> None:
-    from repro.launch.sharding import host_mesh
-
-    mesh = host_mesh(n)
-    runs = {}
-    for label, m in (("1-device", None), (f"{n}-device mesh", mesh)):
-        n0 = len(log.calls)
-        t0 = time.perf_counter()
-        ctl, burst = drain_burst(mesh=m)
-        wall = time.perf_counter() - t0
-        check(burst["drained"] and burst["never_regressed"],
-              f"{label}: drained and never regressed")
-        runs[label] = (trajectory(ctl), log.calls[n0:])
-        print(f"{label}: wall_s={wall:.3f} drain_s={burst['event_loop_s']} "
-              f"admissions_per_s={burst['admissions_per_s']} "
-              f"solves={len(log.calls) - n0}", flush=True)
-    (t1, calls1), (tn, callsn) = runs.values()
-    check(t1 == tn, "sharded and single-device trajectories bit-identical")
-    print("trajectories: bit-identical", flush=True)
-    check(all(k == "single" for k, _ in calls1), "1-device run unsharded")
-    sharded = [devs for k, devs in callsn if k == "sharded"]
-    check(bool(sharded), "the mesh run made sharded solves")
-    per_dev = Counter(str(d) for devs in sharded for d in devs)
-    sizes = Counter(len(devs) for devs in sharded)
-    print(f"sharded solves: {len(sharded)}, chunks per solve: {dict(sizes)}, "
-          f"chunks per device: {dict(per_dev)}", flush=True)
-    full = [devs for devs in sharded if len(devs) == n]
-    check(bool(full) and all(len(set(devs)) == n for devs in full),
-          f"every {n}-chunk solve on {n} distinct devices")
-    check(all(len(set(devs)) == len(devs) for devs in sharded),
-          "no two chunks of one solve share a device")
-
-
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: run only the sharded-solve phase on 4 chips")
-    args = ap.parse_args()
+    ap.parse_args()
 
     from jax import monitoring
 
@@ -325,24 +267,17 @@ def main() -> None:
     t_all = time.perf_counter()
     device = phase_device()
     log = DispatchLog()
-    if args.chips == 4:
-        check(device["count"] >= 4, f"4 chips visible, got {device['count']}")
+    chips = {}
+    for phase, fn in (
+        ("designs", phase_designs),
+        ("burst", lambda: phase_burst(log)),
+    ):
         t0 = time.perf_counter()
-        phase_sharded(log, 4)
-        print(f"phase sharded: {time.perf_counter() - t0:.3f} s", flush=True)
-    else:
-        chips = {}
-        for phase, fn in (
-            ("designs", phase_designs),
-            ("burst", lambda: phase_burst(log)),
-        ):
-            t0 = time.perf_counter()
-            chips[phase] = fn()
-            print(f"phase {phase}: {time.perf_counter() - t0:.3f} s",
-                  flush=True)
-        t0 = time.perf_counter()
-        phase_reference(chips, log)
-        print(f"phase reference: {time.perf_counter() - t0:.3f} s", flush=True)
+        chips[phase] = fn()
+        print(f"phase {phase}: {time.perf_counter() - t0:.3f} s", flush=True)
+    t0 = time.perf_counter()
+    phase_reference(chips, log)
+    print(f"phase reference: {time.perf_counter() - t0:.3f} s", flush=True)
     print(f"compile cache events: {dict(cache)}", flush=True)
     print(f"total: {time.perf_counter() - t_all:.3f} s", flush=True)
     print(json.dumps({"ok": True, "device": device}))

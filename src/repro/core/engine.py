@@ -822,25 +822,13 @@ def finish_execution(
     )
 
 
-def _resolve_backend(backend: str, n_devices: int = 0) -> str:
+def _resolve_backend(backend: str) -> str:
     """Resolve ``"auto"``: exact device backend on any accelerator
     (TPU *or* GPU — see :func:`~repro.core.maxplus._on_accelerator`),
-    host numpy otherwise.  A multi-device scoring mesh also forces the
-    device backend — sharding is a ``"csr-jit"`` capability."""
+    host numpy otherwise."""
     if backend == "auto":
-        on_dev = _engine_on_accelerator() or n_devices > 1
-        return "csr-jit" if on_dev else "edges"
+        return "csr-jit" if _engine_on_accelerator() else "edges"
     return backend
-
-
-def _solve_devices(mesh) -> list:
-    """Flat device list for the scoring mesh (explicit arg wins, else the
-    ambient :func:`repro.launch.sharding.current_mesh`); ``[]`` when no
-    mesh is active.  Lazy import keeps ``repro.core`` importable without
-    touching jax device state through the launch layer."""
-    from repro.launch.sharding import current_mesh, mesh_devices
-
-    return mesh_devices(mesh if mesh is not None else current_mesh())
 
 
 def fuse_stacks(
@@ -896,7 +884,6 @@ def batch_execute_fused(
     *,
     backend: str = "auto",
     pad_shapes: Optional[bool] = None,
-    mesh=None,
 ) -> list[EngineReport]:
     """Solve MANY independent prepared batches in ONE analysis call.
 
@@ -910,19 +897,9 @@ def batch_execute_fused(
     Per-member results are bit-for-bit the standalone results at that
     tolerance (the lambda-search is row-local).  ``with_starts`` is
     deliberately unsupported — scoring paths never need start vectors.
-
-    ``mesh`` (or an ambient :func:`repro.launch.sharding.use_mesh`)
-    shards the fused batch axis across the mesh devices — contiguous row
-    chunks, one concurrent ``"csr-jit"`` solve per device, merged
-    host-side.  Results are bit-identical to the single-device solve at
-    the same (tightest-member) tolerance, so device count never changes
-    which candidate wins.
     """
     assert preps, "need at least one prepared execution to fuse"
-    devices = _solve_devices(mesh)
-    backend = _resolve_backend(backend, len(devices))
-    if backend != "csr-jit":
-        devices = []
+    backend = _resolve_backend(backend)
     if pad_shapes is None:
         pad_shapes = backend in ("dense", "csr-jit")
     fused, slices = fuse_stacks([p.stack for p in preps])
@@ -941,10 +918,7 @@ def batch_execute_fused(
     _CACHE_STATS.record(key)
     for sink in _CACHE_SINKS:
         sink.record(key)
-    periods = mcr_batch(
-        fused, backend=backend, rel_tol=rel_tol, lo0=lo0,
-        devices=devices or None,
-    )
+    periods = mcr_batch(fused, backend=backend, rel_tol=rel_tol, lo0=lo0)
     return [finish_execution(p, periods[s]) for p, s in zip(preps, slices)]
 
 
@@ -962,7 +936,6 @@ def batch_execute(
     pad_shapes: Optional[bool] = None,
     chip_state: Optional[ChipState] = None,
     rate_scale=None,
-    mesh=None,
 ) -> EngineReport:
     """Self-timed steady state of every candidate, in one batched pass.
 
@@ -1001,10 +974,6 @@ def batch_execute(
     dead tile reports an ``inf`` period (hence zero throughput and ``inf``
     energy) — degraded candidates rank in the same batched pass as
     healthy ones.
-
-    ``mesh`` (or an ambient :func:`repro.launch.sharding.use_mesh`)
-    shards the candidate batch axis across the mesh devices exactly as
-    in :func:`batch_execute_fused` — bit-identical, merged host-side.
     """
     # shortcut edges preserve every cycle ratio but are NOT Eq.-4
     # dependencies, so the starts path must build the plain stack
@@ -1014,10 +983,7 @@ def batch_execute(
         rate_scale=rate_scale, relax_shortcuts=not with_starts,
     )
 
-    devices = _solve_devices(mesh)
-    backend = _resolve_backend(backend, len(devices))
-    if backend != "csr-jit":
-        devices = []
+    backend = _resolve_backend(backend)
     if pad_shapes is None:
         pad_shapes = backend in ("dense", "csr-jit")
     stack, lo0 = prep.stack, prep.lo0
@@ -1027,10 +993,7 @@ def batch_execute(
     _CACHE_STATS.record(key)
     for sink in _CACHE_SINKS:
         sink.record(key)
-    periods = mcr_batch(
-        stack, backend=backend, rel_tol=rel_tol, lo0=lo0,
-        devices=devices or None,
-    )
+    periods = mcr_batch(stack, backend=backend, rel_tol=rel_tol, lo0=lo0)
     starts = None
     if with_starts:
         t_mat = maxplus_matrix_batch(stack)

@@ -455,7 +455,6 @@ def mcr_batch(
     backend: str = "auto",
     lo0: Optional[np.ndarray] = None,
     detect_deadlock: bool = False,
-    devices: Optional[Sequence] = None,
 ) -> np.ndarray:
     """Maximum cycle ratio for every row of an :class:`EdgeStack`.
 
@@ -481,21 +480,9 @@ def mcr_batch(
     multi-lambda probing — default when any non-CPU device is present),
     ``"dense"`` (Pallas/jnp max-plus matrix squaring, float32, opt-in), or
     ``"auto"``.
-
-    ``devices`` (``"csr-jit"`` only): two or more jax devices shard the
-    batch axis — contiguous row chunks solved concurrently, one per
-    device, bit-identical to the unsharded solve; a single device pins
-    the solve to it.  Forces ``"csr-jit"`` under ``"auto"``.
     """
     if backend == "auto":
-        backend = (
-            "csr-jit" if (_on_accelerator() or (devices and len(devices) > 1))
-            else "edges"
-        )
-    if devices and backend != "csr-jit":
-        raise ValueError(
-            f"devices= requires the 'csr-jit' backend, got {backend!r}"
-        )
+        backend = "csr-jit" if _on_accelerator() else "edges"
     if backend == "dense":
         if detect_deadlock:
             raise ValueError("detect_deadlock is not supported by 'dense'")
@@ -507,7 +494,7 @@ def mcr_batch(
     if backend == "csr-jit":
         return _mcr_batch_csr(
             stack, max_steps=max_steps, rel_tol=rel_tol, lo0=lo0,
-            detect_deadlock=detect_deadlock, devices=devices,
+            detect_deadlock=detect_deadlock,
         )
     assert backend == "edges", backend
 
@@ -561,13 +548,13 @@ def mcr_batch(
 def _pack_csr_chunk(
     stack: EdgeStack, lo0: Optional[np.ndarray]
 ) -> Optional[tuple]:
-    """Host-side packing of one (chunk of an) EdgeStack for the device
-    bisection: flat batched CSR -> ELL operands + bisection bounds.
+    """Host-side packing of one EdgeStack for the device bisection: flat
+    batched CSR -> ELL operands + bisection bounds.
 
     Returns ``(operands, lo, hi, has_cycle)`` or ``None`` when the
-    chunk has no finite edge at all (every row is acyclic padding — the
+    stack has no finite edge at all (every row is acyclic padding — the
     caller reports those rows as ``-inf`` without a solve).  Packing a
-    row subset independently is exact: the ELL width tracks the chunk's
+    row subset independently is exact: the ELL width tracks the subset's
     own in-degree profile and pad slots carry the ``-inf`` neutral
     element, so per-row results never depend on which rows share the
     pack.  One of three layouts, chosen from the stack alone
@@ -743,7 +730,6 @@ def _mcr_batch_csr(
     lo0: Optional[np.ndarray] = None,
     detect_deadlock: bool = False,
     k_probes: Optional[int] = None,
-    devices: Optional[Sequence] = None,
 ) -> np.ndarray:
     """Device-resident exact lambda-search (the ``"csr-jit"`` backend).
 
@@ -754,14 +740,6 @@ def _mcr_batch_csr(
     host/device round-trips per probe, and every relaxation sweep shrinks
     the interval ``(K+1)x``.  Exact to the same ``rel_tol`` contract as
     ``"edges"``; the two agree to bisection-interval width on every row.
-
-    ``devices`` (two or more jax devices) shards the batch axis: the rows
-    split into ``len(devices)`` contiguous chunks, each packed and solved
-    on its own device with all chunks in flight at once
-    (:func:`repro.kernels.maxplus_bellman.mcr_bisect_device_sharded`).
-    Per-row results are bit-identical to the unsharded solve — the
-    lambda-search is row-local — so device count never changes a result.
-    A single device in ``devices`` pins the unsharded solve to it.
     """
     from repro.kernels import maxplus_bellman as kbell
 
@@ -774,72 +752,17 @@ def _mcr_batch_csr(
     # classic bisection budget over-covers by the same log factor
     steps = max(4, int(math.ceil(max_steps / math.log2(k_probes + 1))) + 1)
 
-    devices = list(devices) if devices else []
-    n_chunks = min(len(devices), b) if len(devices) > 1 else 1
-
-    if n_chunks <= 1:
-        packed = _pack_csr_chunk(stack, lo0)
-        if packed is None:
-            return np.full(b, NEG_INF)
-        operands, lo, hi, has_cycle = packed
-        lo, hi, has_cycle, deadlocked = kbell.mcr_bisect_device(
-            operands, lo, hi, has_cycle,
-            n_actors=n, rel_tol=rel_tol, k_probes=k_probes, max_steps=steps,
-            detect_deadlock=detect_deadlock,
-            device=devices[0] if devices else None,
-        )
-        res = np.where(has_cycle, 0.5 * (lo + hi), NEG_INF)
-        return np.where(deadlocked, np.inf, res) if detect_deadlock else res
-
-    # sharded: contiguous near-equal row chunks, chunk k on devices[k]
-    # (the launch-layer sharding rule, so boundaries match everywhere).
-    # Every chunk is padded with all--inf rows to the LARGEST chunk's row
-    # count: with a bucket-padded caller batch the per-device solve shape
-    # is then identical across chunks AND across calls, so each device
-    # compiles once and stays on its cached executable.  Pad rows carry
-    # no finite edge — they start converged and never touch real rows.
-    from repro.launch.sharding import row_chunks
-
-    res = np.full(b, NEG_INF)
-    dead = np.zeros(b, dtype=bool)
-    chunk_slices = row_chunks(b, n_chunks)
-    rows_max = max(sl.stop - sl.start for sl in chunk_slices)
-    chunks, slices, devs = [], [], []
-    for k, sl in enumerate(chunk_slices):
-        m = sl.stop - sl.start
-        pad = rows_max - m
-        src, dst = stack.src[sl], stack.dst[sl]
-        tok, wts = stack.tokens[sl], stack.weights[sl]
-        lo0_c = lo0[sl] if lo0 is not None else None
-        if pad:
-            src = np.concatenate([src, np.zeros((pad, e), dtype=src.dtype)])
-            dst = np.concatenate([dst, np.zeros((pad, e), dtype=dst.dtype)])
-            tok = np.concatenate([tok, np.ones((pad, e), dtype=tok.dtype)])
-            wts = np.concatenate([wts, np.full((pad, e), NEG_INF)])
-            if lo0_c is not None:
-                lo0_c = np.concatenate([lo0_c, np.full(pad, NEG_INF)])
-        sub = EdgeStack(n_actors=n, src=src, dst=dst, tokens=tok, weights=wts)
-        packed = _pack_csr_chunk(sub, lo0_c)
-        if packed is None:
-            continue                       # all-padding rows stay -inf
-        chunks.append(packed)
-        slices.append(sl)
-        devs.append(devices[k % len(devices)])
-    if not chunks:
-        return res
-    lo, hi, has_cycle, deadlocked = kbell.mcr_bisect_device_sharded(
-        chunks, devs,
+    packed = _pack_csr_chunk(stack, lo0)
+    if packed is None:
+        return np.full(b, NEG_INF)
+    operands, lo, hi, has_cycle = packed
+    lo, hi, has_cycle, deadlocked = kbell.mcr_bisect_device(
+        operands, lo, hi, has_cycle,
         n_actors=n, rel_tol=rel_tol, k_probes=k_probes, max_steps=steps,
         detect_deadlock=detect_deadlock,
     )
-    for k, sl in enumerate(slices):
-        m = sl.stop - sl.start
-        part = slice(k * rows_max, k * rows_max + m)
-        res[sl] = np.where(
-            has_cycle[part], 0.5 * (lo[part] + hi[part]), NEG_INF
-        )
-        dead[sl] = deadlocked[part]
-    return np.where(dead, np.inf, res) if detect_deadlock else res
+    res = np.where(has_cycle, 0.5 * (lo + hi), NEG_INF)
+    return np.where(deadlocked, np.inf, res) if detect_deadlock else res
 
 
 def _pow2(x: int) -> int:

@@ -656,7 +656,6 @@ def optimize_binding_graph(
     backend: str = "auto",
     chip_state: Optional[ChipState] = None,
     rate_scale=None,
-    mesh=None,
 ) -> OptimizeReport:
     """Graph-level search core: optimize actor-to-tile bindings of ``app``.
 
@@ -700,14 +699,6 @@ def optimize_binding_graph(
     dead tile score ``inf`` and lose naturally, but callers searching a
     degraded chip should pass alive-only ``allowed_tiles`` (and repaired
     seeds) so the search budget is not wasted on infeasible rows.
-
-    ``mesh`` shards every generation's population scoring across the mesh
-    devices (:func:`~repro.core.engine.batch_execute` ``mesh=`` path):
-    each device solves a contiguous population slice with the exact
-    ``"csr-jit"`` backend and the elite archive merges host-side.  The
-    per-row lambda-search is row-local, so the whole search trajectory —
-    every generation's scores, every archive update, the final pick — is
-    bit-identical to the single-device run at the same ``rng_seed``.
     """
     search = _BindingSearch(
         app, hw, single_order,
@@ -731,7 +722,6 @@ def optimize_binding_graph(
         rep = batch_execute(
             app, pop, hw, orders, backend=backend, rel_tol=rel_tol,
             with_energy=True, chip_state=chip_state, rate_scale=rate_scale,
-            mesh=mesh,
         )
         search.tell(*_alive_scores(rep))
     return search.report()
@@ -750,7 +740,6 @@ def optimize_binding_graphs_fused(
     tasks: Sequence[dict],
     *,
     backend: str = "auto",
-    mesh=None,
 ) -> list[OptimizeReport]:
     """Run MANY independent binding searches with FUSED scoring.
 
@@ -771,8 +760,7 @@ def optimize_binding_graphs_fused(
     run and could reorder near-tie elites, breaking reproducibility —
     so a tick where every search is in the same phase (the common case:
     equal generation counts) is exactly one call.  Reports come back in
-    task order.  ``mesh`` shards each fused solve's batch axis over the
-    mesh devices (bit-identical — see :func:`optimize_binding_graph`).
+    task order.
     """
     searches = [
         _BindingSearch(
@@ -801,7 +789,7 @@ def optimize_binding_graphs_fused(
             groups[rel_tol][0].append(s)
             groups[rel_tol][1].append(prep)
         for rel_tol, (members, preps) in groups.items():
-            reports = batch_execute_fused(preps, backend=backend, mesh=mesh)
+            reports = batch_execute_fused(preps, backend=backend)
             for s, rep in zip(members, reports):
                 s.tell(*_alive_scores(rep))
     return [s.report() for s in searches]

@@ -547,7 +547,6 @@ class AdmissionController:
         full_rebalance_every: int = 8,
         region_radius: int = 1,
         fused_scoring: bool = True,
-        mesh=None,
     ):
         if placement not in ("isolated", "joint"):
             raise ValueError(
@@ -594,10 +593,6 @@ class AdmissionController:
         # its component searches in lockstep, one fused EdgeStack
         # analysis per generation (see _optimize_region)
         self.fused_scoring = bool(fused_scoring)
-        # scoring mesh: shards every rebalance's population scoring across
-        # its devices (bit-identical to single-device — see
-        # optimize_binding_graph's mesh= contract); None = unsharded
-        self.mesh = mesh
         # rebalance deferral (the serving burst path): while a deferral
         # is active, _rebalance only records the event; flush_rebalances
         # merges all pending events into ONE region rebalance
@@ -1636,7 +1631,6 @@ class AdmissionController:
                 allowed_tiles=footprint, objective=self.objective,
                 chip_state=self.chip,
                 rate_scale=self._union_rate_scale(arts),
-                mesh=self.mesh,
             )
         union_orders = project_order(order, rep.binding, self.hw.n_tiles)
         thr = (
@@ -1852,7 +1846,7 @@ class AdmissionController:
             tasks.append(task)
             contexts.append(ctx)
         with record_cache_stats(self.cache_stats):
-            reps = optimize_binding_graphs_fused(tasks, mesh=self.mesh)
+            reps = optimize_binding_graphs_fused(tasks)
         for (comp, order, offsets), rep in zip(contexts, reps):
             self._apply_component_result(comp, order, offsets, rep)
 
@@ -1957,9 +1951,7 @@ class AdmissionController:
         hw = task.pop("hw")
         single_order = task.pop("single_order")
         with record_cache_stats(self.cache_stats):
-            rep = optimize_binding_graph(
-                app, hw, single_order, mesh=self.mesh, **task
-            )
+            rep = optimize_binding_graph(app, hw, single_order, **task)
         self._apply_component_result(names, order, offsets, rep)
         return max(float(rep.period), floor)
 

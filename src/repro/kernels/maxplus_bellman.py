@@ -322,19 +322,16 @@ def _dispatch_bisect(
     max_steps: int,
     max_rounds: int,
     detect_deadlock: bool,
-    device=None,
 ):
-    """Enqueue one chunk's bisection (inside a ``jax.enable_x64`` scope).
+    """Enqueue the bisection (inside a ``jax.enable_x64`` scope).
 
-    Returns the five result arrays WITHOUT forcing them to host: jax
-    dispatch is async, so a caller placing successive chunks on different
-    devices overlaps their execution and synchronizes only at the final
-    ``np.asarray`` gather.  ``device=None`` keeps the default placement.
+    Returns the five result arrays as jax arrays on the default device,
+    WITHOUT forcing them to host: the caller gathers with ``np.asarray``.
     """
     def put(x, dtype):
-        arr = np.asarray(x, dtype=dtype)
-        return jax.device_put(arr, device) if device is not None \
-            else jnp.asarray(arr)
+        # numpy casts on the host: ``jnp.asarray(x, dtype)`` would run a
+        # jitted convert on the device, one more compile per new shape
+        return jnp.asarray(np.asarray(x, dtype=dtype))
 
     # (src, w, t) of the ELL, then of the per-replica ELL when split
     ops_dev = tuple(put(x, np.int32 if i % 3 == 0 else np.float64)
@@ -354,7 +351,7 @@ def _dispatch_bisect(
 
 
 def _tally(counts, operands, b: int, k_probes: int) -> dict:
-    """One chunk's loop counts, pulled to the host, as solve counters.
+    """A solve's loop counts, pulled to the host, as solve counters.
 
     ``steps``: bisection steps; ``rounds``: relaxation rounds, one probe
     loop after another; ``probe_rounds``: (row, probe) pairs those rounds
@@ -381,31 +378,27 @@ def _tally(counts, operands, b: int, k_probes: int) -> dict:
     }
 
 
-def _layout(operand_sets) -> str:
-    """A solve's layout, over the chunks it packed: ``"per_row"`` when a
-    chunk packs row by row (one replica per ELL node), else ``"split"``
-    when a chunk carries per-replica operands, else ``"shared"``."""
-    if any(np.shape(ops[1])[2] == 1 for ops in operand_sets):
+def _layout(operands) -> str:
+    """A solve's layout, from its packed operands: ``"per_row"`` when it
+    packs row by row (one replica per ELL node), else ``"split"`` when it
+    carries per-replica operands, else ``"shared"``."""
+    if np.shape(operands[1])[2] == 1:
         return "per_row"
-    if any(len(ops) > 3 for ops in operand_sets):
+    if len(operands) > 3:
         return "split"
     return "shared"
 
 
-def _record(span, tallies: list) -> None:
+def _record(span, tally: dict) -> None:
     """Adds one solve's counters (``solve.calls``, ``solve.shared_calls``,
     ``solve.split_calls`` and ``solve.<key>`` of :func:`_tally`) to the
     attached recorder and puts them on the solve's ``device_solve`` span,
-    beside its ``layout``.  The chunks of a sharded solve run side by side
-    on their devices, so its steps and rounds are those of its longest
-    chunk; its pairs and relaxations add up over the chunks."""
-    total = {key: (max if key in ("steps", "rounds") else sum)(
-        t[key] for t in tallies) for key in tallies[0]}
-    span.attrs.update(total)
+    beside its ``layout``."""
+    span.attrs.update(tally)
     obs.count("solve.calls")
     obs.count("solve.shared_calls", int(span.attrs["layout"] == "shared"))
     obs.count("solve.split_calls", int(span.attrs["layout"] == "split"))
-    for key, n in total.items():
+    for key, n in tally.items():
         obs.count(f"solve.{key}", n)
 
 
@@ -418,7 +411,6 @@ def mcr_bisect_device(
     max_steps: int = 40,
     max_rounds: int = 0,
     detect_deadlock: bool = False,
-    device=None,
 ):
     """Host-facing entry: numpy CSR/ELL arrays in, numpy results out.
 
@@ -428,9 +420,7 @@ def mcr_bisect_device(
     Scopes ``jax.enable_x64(True)`` around conversion, tracing and
     execution so the bisection runs in float64 without flipping the
     process-global jax precision (the Pallas semiring kernels stay
-    float32).  ``device`` pins the whole solve to one specific jax device
-    (the sharded path's per-chunk placement); ``None`` keeps the default
-    device.
+    float32).  The solve runs on the default device.
 
     The call is the program's ``device_solve`` span; its ``layout`` attr
     says how the rows were packed (:func:`_layout`).  While a recorder is
@@ -438,72 +428,16 @@ def mcr_bisect_device(
     back from the device and are recorded (:func:`_record`); else they
     never leave it.
     """
-    with obs.span("device_solve", layout=_layout([operands])) as sp, \
+    with obs.span("device_solve", layout=_layout(operands)) as sp, \
             jax.enable_x64(True):
         out = _dispatch_bisect(
             operands, lo, hi, has_cycle,
             n_actors=n_actors, rel_tol=rel_tol, k_probes=k_probes,
             max_steps=max_steps, max_rounds=max_rounds,
-            detect_deadlock=detect_deadlock, device=device,
+            detect_deadlock=detect_deadlock,
         )
         lo, hi, has_cycle, deadlocked = (np.asarray(x) for x in out[:4])
         if obs.recorder() is not None:
-            _record(sp, [_tally(out[4], operands, len(lo), k_probes)])
+            _record(sp, _tally(out[4], operands, len(lo), k_probes))
     return lo, hi, has_cycle, deadlocked
 
-
-def mcr_bisect_device_sharded(
-    chunks,
-    devices,
-    *,
-    n_actors: int,
-    rel_tol: float,
-    k_probes: int = DEFAULT_K_PROBES,
-    max_steps: int = 40,
-    max_rounds: int = 0,
-    detect_deadlock: bool = False,
-):
-    """Shard-friendly solve entry: one bisection chunk per mesh device.
-
-    ``chunks`` is a sequence of ``(operands, lo, hi, has_cycle)`` tuples —
-    row-contiguous slices of one batched lambda-search, each packed
-    host-side by :func:`repro.core.maxplus._mcr_batch_csr` — and
-    ``devices`` the matching jax devices (chunk k runs on
-    ``devices[k % len(devices)]``).  Every chunk is DISPATCHED before any
-    is gathered: jax execution is async, so chunks run concurrently
-    across the mesh and the host blocks once, at the ``np.asarray``
-    gather.
-
-    Per-row results are bit-identical to the unsharded solve: the
-    bisection is row-local (each row's probe lambdas depend only on its
-    own interval, and converged rows never move), so splitting the batch
-    changes which rows ride along in a convergence loop but never any
-    row's trajectory.  A chunk whose rows all converge early simply
-    stops — sharding also stops slow rows dragging the whole batch
-    through extra relaxation sweeps.
-
-    Returns concatenated ``(lo, hi, has_cycle, deadlocked)`` rows in
-    chunk order.  The call is one ``device_solve`` span and records one
-    solve, as :func:`mcr_bisect_device` does.
-    """
-    assert chunks, "need at least one chunk"
-    devices = list(devices) or [None]
-    layout = _layout([ops for ops, _, _, _ in chunks])
-    with obs.span("device_solve", layout=layout) as sp, jax.enable_x64(True):
-        futs = [
-            _dispatch_bisect(
-                operands, lo, hi, has_cycle,
-                n_actors=n_actors, rel_tol=rel_tol, k_probes=k_probes,
-                max_steps=max_steps, max_rounds=max_rounds,
-                detect_deadlock=detect_deadlock,
-                device=devices[k % len(devices)],
-            )
-            for k, (operands, lo, hi, has_cycle) in enumerate(chunks)
-        ]
-        parts = [tuple(np.asarray(x) for x in out[:4]) for out in futs]
-        if obs.recorder() is not None:
-            _record(sp, [_tally(out[4], operands, len(lo), k_probes)
-                         for out, (operands, lo, _, _) in zip(futs, chunks)])
-    return tuple(
-        np.concatenate([p[i] for p in parts]) for i in range(4)
-    )

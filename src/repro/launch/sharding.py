@@ -67,60 +67,6 @@ def batch_axes(mesh: Mesh):
     return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
 
 
-def mesh_devices(mesh: Optional[Mesh]) -> list:
-    """Flat device list of ``mesh`` (row-major over its axes); ``[]`` if None.
-
-    The sharded analysis path (:func:`repro.core.engine.batch_execute` /
-    ``batch_execute_fused``) chunks the EdgeStack batch axis over exactly
-    this ordering, so chunk k always lands on the same device across
-    calls — per-device executable caches stay warm.
-    """
-    if mesh is None:
-        return []
-    return list(np.asarray(mesh.devices).reshape(-1))
-
-
-def host_mesh(n_devices: Optional[int] = None, *, axis: str = "data") -> Mesh:
-    """A 1-D data mesh over the visible devices (CPU host devices included).
-
-    The serving benchmarks force ``N`` host devices with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` and build the
-    scoring mesh here; on a real accelerator host the same call meshes the
-    accelerators.  Asking for more devices than are visible raises: a
-    mesh silently smaller than requested would mislabel every result
-    measured on it.
-    """
-    devs = jax.devices()
-    if n_devices is not None:
-        if n_devices < 1:
-            raise ValueError(f"n_devices must be >= 1, got {n_devices}")
-        if n_devices > len(devs):
-            raise ValueError(
-                f"host_mesh({n_devices}) needs {n_devices} devices, but "
-                f"only {len(devs)} are visible: {devs}"
-            )
-        devs = devs[: int(n_devices)]
-    return Mesh(np.asarray(devs), (axis,))
-
-
-def row_chunks(n_rows: int, n_parts: int) -> list[slice]:
-    """Contiguous near-equal row slices: the batch-axis sharding rule.
-
-    Mirrors ``np.array_split`` boundaries (first ``n_rows % n_parts``
-    chunks get one extra row); empty chunks are dropped so every returned
-    slice maps to real work on its device.
-    """
-    n_parts = max(1, min(int(n_parts), int(n_rows)))
-    base, extra = divmod(int(n_rows), n_parts)
-    out, start = [], 0
-    for k in range(n_parts):
-        size = base + (1 if k < extra else 0)
-        if size:
-            out.append(slice(start, start + size))
-        start += size
-    return out
-
-
 # ======================================================================
 # activations
 # ======================================================================
@@ -134,8 +80,6 @@ def logical_shard(x: jax.Array, kind: str) -> jax.Array:
         spec = _fit(mesh, x.shape, (b, None, None))
     elif kind == "logits":  # (B, S, V)
         spec = _fit(mesh, x.shape, (b, None, "model"))
-    elif kind == "rows":  # (B, ...) row-batched analysis arrays
-        spec = _fit(mesh, x.shape, (b,) + (None,) * (x.ndim - 1))
     else:
         return x
     return jax.lax.with_sharding_constraint(x, spec)

@@ -256,7 +256,8 @@ def test_bucket_sizes_pow2ish():
         assert bx >= x and bx <= 2 * x
 
 
-def test_pad_stack_to_buckets_preserves_periods(projected):
+@pytest.mark.parametrize("backend", ("edges", "csr-jit"))
+def test_pad_stack_to_buckets_preserves_periods(projected, backend):
     from repro.core import pad_stack_to_buckets
 
     app, order, bindings = projected
@@ -266,8 +267,8 @@ def test_pad_stack_to_buckets_preserves_periods(projected):
     assert padded.n_graphs >= stack.n_graphs
     assert padded.n_edges >= stack.n_edges
     np.testing.assert_allclose(
-        mcr_batch(stack, backend="edges"),
-        mcr_batch(padded, backend="edges")[: stack.n_graphs],
+        mcr_batch(stack, backend=backend),
+        mcr_batch(padded, backend=backend)[: stack.n_graphs],
         rtol=1e-9,
     )
 

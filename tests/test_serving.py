@@ -48,7 +48,14 @@ def _bindings(app, n_rows, seed):
 # ======================================================================
 # engine layer: fused stacks solve row-identically
 # ======================================================================
-def test_fuse_stacks_rows_solve_identically():
+# "edges" is what "auto" resolves to on a host; "csr-jit" is the device
+# solve, whose fused (and bucket-padded) pack differs from each member's
+# own pack -- row-locality says no row's period may notice
+BACKENDS = pytest.mark.parametrize("backend", ("edges", "csr-jit"))
+
+
+@BACKENDS
+def test_fuse_stacks_rows_solve_identically(backend):
     preps = []
     for seed, rows in ((1, 3), (2, 5), (3, 2)):
         app, order = _compiled(seed)
@@ -57,13 +64,14 @@ def test_fuse_stacks_rows_solve_identically():
         preps.append(prepare_execution(app, b, DYNAP_SE, ob))
     fused, slices = fuse_stacks([p.stack for p in preps])
     assert fused.n_graphs == sum(p.n_rows for p in preps)
-    got = mcr_batch(fused, backend="edges")
+    got = mcr_batch(fused, backend=backend)
     for p, s in zip(preps, slices):
-        alone = mcr_batch(p.stack, backend="edges")
+        alone = mcr_batch(p.stack, backend=backend)
         np.testing.assert_array_equal(got[s], alone)
 
 
-def test_batch_execute_fused_matches_sequential():
+@BACKENDS
+def test_batch_execute_fused_matches_sequential(backend):
     preps, reports = [], []
     for seed, rows in ((4, 4), (5, 3)):
         app, order = _compiled(seed)
@@ -73,10 +81,10 @@ def test_batch_execute_fused_matches_sequential():
             prepare_execution(app, b, DYNAP_SE, ob, with_energy=True)
         )
         reports.append(
-            batch_execute(app, b, DYNAP_SE, ob, backend="edges",
+            batch_execute(app, b, DYNAP_SE, ob, backend=backend,
                           with_energy=True)
         )
-    fused_reports = batch_execute_fused(preps, backend="edges")
+    fused_reports = batch_execute_fused(preps, backend=backend)
     for fr, sr in zip(fused_reports, reports):
         np.testing.assert_allclose(fr.periods, sr.periods, rtol=1e-12)
         np.testing.assert_allclose(fr.energies, sr.energies, rtol=1e-12)
@@ -96,19 +104,20 @@ def _task(seed, *, generations, population=10):
     )
 
 
-def test_fused_binding_search_bit_matches_sequential():
+@BACKENDS
+def test_fused_binding_search_bit_matches_sequential(backend):
     """Equal generation counts: every tick fuses into exactly one solve,
     and each search's result is bit-for-bit its standalone run."""
     tasks = [_task(7, generations=2), _task(8, generations=2)]
     seq = [
         optimize_binding_graph(
-            t["app"], t["hw"], t["single_order"],
+            t["app"], t["hw"], t["single_order"], backend=backend,
             **{k: v for k, v in t.items()
                if k not in ("app", "hw", "single_order")},
         )
         for t in tasks
     ]
-    fused = optimize_binding_graphs_fused(tasks)
+    fused = optimize_binding_graphs_fused(tasks, backend=backend)
     for f, s in zip(fused, seq):
         np.testing.assert_array_equal(f.binding, s.binding)
         assert f.period == s.period
@@ -117,20 +126,21 @@ def test_fused_binding_search_bit_matches_sequential():
                [g.best_period for g in s.history]
 
 
-def test_fused_binding_search_mixed_generations():
+@BACKENDS
+def test_fused_binding_search_mixed_generations(backend):
     """Unequal horizons exercise the per-(tick, tolerance) grouping: a
     finished search's tight final re-score must never be fused with
     another search's loose generation scoring."""
     tasks = [_task(9, generations=1), _task(10, generations=3)]
     seq = [
         optimize_binding_graph(
-            t["app"], t["hw"], t["single_order"],
+            t["app"], t["hw"], t["single_order"], backend=backend,
             **{k: v for k, v in t.items()
                if k not in ("app", "hw", "single_order")},
         )
         for t in tasks
     ]
-    fused = optimize_binding_graphs_fused(tasks)
+    fused = optimize_binding_graphs_fused(tasks, backend=backend)
     for f, s in zip(fused, seq):
         np.testing.assert_array_equal(f.binding, s.binding)
         assert f.period == s.period
@@ -235,162 +245,6 @@ def test_serving_queue_skips_evicting_non_resident():
     assert stats["skipped"] == 1 and stats["admitted"] == 1
     kinds = {t.app: t.status for t in q.tickets}
     assert kinds[names[1]] == "skipped" and kinds[names[0]] == "ok"
-
-
-# ======================================================================
-# sharded scoring (ISSUE 10 tentpole): device-chunked solves and the
-# mesh= search path are bit-identical to single-device runs
-# ======================================================================
-def _live_stack(b, seed, n=6, e=18):
-    from repro.core.maxplus import EdgeStack
-
-    rng = np.random.default_rng(seed)
-    src = rng.integers(0, n, size=(b, e))
-    dst = rng.integers(0, n, size=(b, e))
-    tok = rng.integers(0, 3, size=(b, e))
-    w = rng.uniform(0.1, 5.0, size=(b, e))
-    src[:, 0] = dst[:, 0] = 0
-    tok[:, 0] = 1                       # token-carrying self loop: live
-    return EdgeStack(n_actors=n, src=src, dst=dst, tokens=tok, weights=w)
-
-
-def test_mcr_batch_sharded_chunks_bit_identical():
-    """Row-chunked multi-device solves (same CPU device repeated — the
-    chunking logic is device-count-driven) equal the unsharded solve
-    bit-for-bit, including chunk counts that do not divide the batch."""
-    import jax
-
-    dev = jax.devices()[0]
-    for b in (3, 13, 64):
-        stack = _live_stack(b, seed=b)
-        ref = mcr_batch(stack, backend="csr-jit")
-        for n_dev in (2, 3, 4, 7):
-            got = mcr_batch(
-                stack, backend="csr-jit", devices=[dev] * n_dev
-            )
-            np.testing.assert_array_equal(got, ref)
-
-
-def test_mcr_batch_devices_requires_csr_jit():
-    import jax
-
-    stack = _live_stack(4, seed=1)
-    with pytest.raises(ValueError):
-        mcr_batch(stack, backend="edges", devices=jax.devices() * 2)
-
-
-def test_host_mesh_raises_when_too_few_devices_visible():
-    """A mesh silently smaller than requested would mislabel every
-    measurement taken on it: asking past the visible devices raises."""
-    import jax
-
-    from repro.launch.sharding import host_mesh
-
-    n = len(jax.devices())
-    assert host_mesh(n).devices.size == n
-    with pytest.raises(ValueError, match="visible"):
-        host_mesh(n + 1)
-
-
-def test_batch_execute_mesh_matches_unsharded():
-    import jax
-    from jax.sharding import Mesh
-
-    app, order = _compiled(11)
-    b = _bindings(app, 7, 11)
-    ob = project_order_batch(order, b)
-    ref = batch_execute(app, b, DYNAP_SE, ob, backend="csr-jit",
-                        with_energy=True)
-    mesh = Mesh(np.asarray([jax.devices()[0]] * 3), ("data",))
-    got = batch_execute(app, b, DYNAP_SE, ob, mesh=mesh, with_energy=True)
-    np.testing.assert_array_equal(got.periods, ref.periods)
-    np.testing.assert_array_equal(got.energies, ref.energies)
-
-
-def test_optimize_mesh_trajectory_bit_identical():
-    """mesh= sharded search == single-device csr-jit search: same
-    per-generation history, same elite, same final binding/period."""
-    import jax
-    from jax.sharding import Mesh
-
-    t = _task(21, generations=3)
-    kw = {k: v for k, v in t.items()
-          if k not in ("app", "hw", "single_order")}
-    ref = optimize_binding_graph(
-        t["app"], t["hw"], t["single_order"], backend="csr-jit", **kw
-    )
-    mesh = Mesh(np.asarray([jax.devices()[0]] * 4), ("data",))
-    got = optimize_binding_graph(
-        t["app"], t["hw"], t["single_order"], mesh=mesh, **kw
-    )
-    np.testing.assert_array_equal(got.binding, ref.binding)
-    assert got.period == ref.period
-    assert [g.best_period for g in got.history] == \
-           [g.best_period for g in ref.history]
-
-    fused_ref = optimize_binding_graphs_fused(
-        [_task(22, generations=2)], backend="csr-jit"
-    )
-    fused_got = optimize_binding_graphs_fused(
-        [_task(22, generations=2)], mesh=mesh
-    )
-    np.testing.assert_array_equal(
-        fused_got[0].binding, fused_ref[0].binding
-    )
-    assert fused_got[0].period == fused_ref[0].period
-
-
-def test_optimize_mesh_forced_host_devices_subprocess():
-    """The acceptance check: under a REAL forced 4-device host platform
-    (XLA_FLAGS must precede the jax import, hence the subprocess), the
-    host_mesh(4) search trajectory is bit-identical to the unsharded
-    one at the same rng_seed."""
-    import os
-    import subprocess
-    import sys
-
-    script = r"""
-import numpy as np
-from repro.core import (
-    DYNAP_SE, optimize_binding_graph, partition_greedy,
-    sdfg_from_clusters, single_tile_order, small_app,
-)
-from repro.launch.sharding import host_mesh
-import jax
-assert len(jax.devices()) == 4, jax.devices()
-
-snn = small_app(150, 1800, seed=33)
-cl = partition_greedy(snn, DYNAP_SE)
-app = sdfg_from_clusters(cl, hw=DYNAP_SE)
-order, _ = single_tile_order(cl, DYNAP_SE)
-kw = dict(
-    seed_bindings={"s": np.arange(app.n_actors) % DYNAP_SE.n_tiles},
-    population=8, generations=2, elite=4, rng_seed=0,
-)
-ref = optimize_binding_graph(app, DYNAP_SE, order, backend="csr-jit", **kw)
-got = optimize_binding_graph(
-    app, DYNAP_SE, order, mesh=host_mesh(4), **kw
-)
-assert got.period == ref.period, (got.period, ref.period)
-assert np.array_equal(got.binding, ref.binding)
-assert [g.best_period for g in got.history] == \
-    [g.best_period for g in ref.history]
-print("IDENTICAL")
-"""
-    env = os.environ.copy()
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=4"
-    ).strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in ("src", env.get("PYTHONPATH", "")) if p
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", script], env=env, text=True,
-        capture_output=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "IDENTICAL" in proc.stdout
 
 
 # ======================================================================

@@ -312,17 +312,20 @@ def test_shared_calls_count_shared_solves_only():
     assert layouts == ["shared", "per_row", "shared", "split"]
 
 
-@pytest.mark.parametrize("name", ["live_pads", "ring_sc"])
-def test_sharded_shared_stack_matches_unsharded(name):
+@pytest.mark.parametrize("name, layout", [("live_pads", "per_row"),
+                                          ("ring_sc", "per_row")])
+def test_fused_member_keeps_its_periods(name, layout):
+    """A shared stack fused with a per-row one solves, on its rows,
+    bit-identically to the stack alone, in one call of the fused pack's
+    layout."""
     make, kw = SHARED[name]
     stack = make()
-    dev = jax.devices()[0]
-    with obs.recording() as one:
-        a = mp._mcr_batch_csr(stack, **kw)
-    with obs.recording() as two:
-        b = mp._mcr_batch_csr(stack, devices=[dev, dev], **kw)
-    np.testing.assert_array_equal(_bits(a), _bits(b))
-    for rec in (one, two):
-        assert rec.counters["solve.shared_calls"] == 1
-    span = next(s for s in two.spans if s.name == "device_solve")
-    assert span.attrs["layout"] == "shared"
+    fused, (rows, _) = fuse_stacks([stack, _disjoint_topologies()])
+    assert mp._choose_layout(fused)[0] == layout
+    alone = mp._mcr_batch_csr(stack, **kw)
+    with obs.recording() as rec:
+        got = mp._mcr_batch_csr(fused, **kw)
+    np.testing.assert_array_equal(_bits(got[rows]), _bits(alone))
+    assert rec.counters["solve.calls"] == 1
+    span = next(s for s in rec.spans if s.name == "device_solve")
+    assert span.attrs["layout"] == layout

@@ -152,38 +152,62 @@ def test_split_counters_count_both_groups():
     assert span.attrs["layout"] == "split"
 
 
-def test_layout_of_a_solve_over_its_chunks():
-    """A sharded solve is as shared as its least shared chunk."""
-    per_row = mp._pack_per_row(_split_stack(), None)[0]
-    shared = mp._pack_csr_chunk(_ring_stack(3, 16, 1, shortcuts=True),
-                                None)[0]
-    split = mp._pack_csr_chunk(_split_stack(), None)[0]
-    assert kbell._layout([shared, shared]) == "shared"
-    assert kbell._layout([split, shared]) == "split"
-    assert kbell._layout([split, split]) == "split"
-    assert kbell._layout([split, per_row]) == "per_row"
-    assert kbell._layout([per_row]) == "per_row"
+@pytest.mark.parametrize("layout", ["shared", "split", "per_row"])
+def test_layout_names_the_pack(layout):
+    """``_layout`` reads the pack's kind off its operands alone."""
+    packs = {
+        "shared": lambda: mp._pack_csr_chunk(
+            _ring_stack(3, 16, 1, shortcuts=True), None),
+        "split": lambda: mp._pack_csr_chunk(_split_stack(), None),
+        "per_row": lambda: mp._pack_per_row(_split_stack(), None),
+    }
+    assert kbell._layout(packs[layout]()[0]) == layout
 
 
-def test_sharded_split_solve_counts_one_split_call():
+def test_fused_split_member_counts_one_split_call():
+    """Binding candidates fused with a stack of identical rows of the
+    same size keep their periods, and the fused solve is one call."""
+    from repro.core import fuse_stacks
+
     stack = _split_stack(b=6)
-    dev = jax.devices()[0]
-    with obs.recording() as one:
-        a = mp._mcr_batch_csr(stack)
-    with obs.recording() as two:
-        b = mp._mcr_batch_csr(stack, devices=[dev, dev])
-    np.testing.assert_array_equal(a, b)
-    for rec in (one, two):
-        assert rec.counters["solve.calls"] == 1
-        assert rec.counters["solve.split_calls"] == 1
-        assert rec.counters["solve.shared_calls"] == 0
-        span = next(s for s in rec.spans if s.name == "device_solve")
-        assert span.attrs["layout"] == "split"
+    other = stack_graphs([live_graph(n=stack.n_actors)] * 3)
+    fused, (rows, _) = fuse_stacks([stack, other])
+    alone = mp._mcr_batch_csr(stack)
+    with obs.recording() as rec:
+        got = mp._mcr_batch_csr(fused)
+    np.testing.assert_array_equal(got[rows], alone)
+    assert rec.counters["solve.calls"] == 1
+    assert rec.counters["solve.split_calls"] == 1
+    assert rec.counters["solve.shared_calls"] == 0
+    span = next(s for s in rec.spans if s.name == "device_solve")
+    assert span.attrs["layout"] == "split"
+
+
+def test_a_solve_lowers_one_program():
+    """The host casts every operand before it goes to the device: a solve
+    lowers its ``csr_bisect`` and nothing else (a cast on the device would
+    lower one more program for every new shape)."""
+    from jax import monitoring
+
+    lowered = []
+
+    def on_duration(event, duration, **_):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(duration)
+
+    kbell._csr_bisect.clear_cache()
+    monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        mp._mcr_batch_csr(_split_stack(b=5, n=13))
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+    assert kbell._csr_bisect._cache_size() == 1
+    assert len(lowered) == 1
 
 
 def _pad(stack, rows):
-    """``stack`` with ``rows`` all--inf rows appended, as a sharded chunk
-    or a bucket-padded batch carries them."""
+    """``stack`` with ``rows`` all--inf rows appended, as a bucket-padded
+    batch carries them, alone or fused."""
     e = stack.n_edges
     cat = lambda a, fill: np.concatenate([a, np.full((rows, e), fill,
                                                      dtype=a.dtype)])
@@ -200,33 +224,6 @@ def test_pad_rows_add_no_live_and_no_probe_rounds():
         mp._mcr_batch_csr(padded)
     c = rec.counters
     assert c["solve.probe_rounds"] == c["solve.rounds"] * 1 * K
-
-
-def test_sharded_counters_add_over_chunks():
-    """Chunks run side by side: a sharded solve's steps and rounds are its
-    longest chunk's, its pairs and relaxations add over the chunks."""
-    rng = np.random.default_rng(9)
-    stack = stack_graphs([random_live_sdfg(rng, 8) for _ in range(4)])
-    dev = jax.devices()[0]
-    with obs.recording() as one:
-        a = mp._mcr_batch_csr(stack)
-    with obs.recording() as two:
-        b = mp._mcr_batch_csr(stack, devices=[dev, dev])
-    np.testing.assert_array_equal(a, b)
-    assert two.counters["solve.calls"] == 1
-    halves = []
-    for sl in (slice(0, 2), slice(2, 4)):
-        with obs.recording() as rec:
-            mp._mcr_batch_csr(mp.EdgeStack(
-                n_actors=stack.n_actors, src=stack.src[sl], dst=stack.dst[sl],
-                tokens=stack.tokens[sl], weights=stack.weights[sl]))
-        halves.append(rec.counters)
-    for key in ("solve.steps", "solve.rounds"):
-        assert two.counters[key] == max(halves[0][key], halves[1][key]), key
-    for key in ("solve.probe_rounds", "solve.live_probe_rounds",
-                "solve.relaxations"):
-        assert two.counters[key] == halves[0][key] + halves[1][key], key
-    assert one.counters["solve.calls"] == 1
 
 
 def _deadlock_stack():
